@@ -31,6 +31,9 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_subprocess(smoke: bool = False) -> None:
     """Re-exec under the forced device count (512 full, 16 smoke)."""
     env = dict(os.environ)
+    # the forced devices are host devices: pin the child to the CPU so it
+    # never reaches for an accelerator the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     n = 16 if smoke else 512
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = os.pathsep.join(

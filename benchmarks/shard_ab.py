@@ -37,6 +37,9 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_subprocess(smoke: bool = False) -> None:
     """Re-exec the benchmark under 8 forced host devices."""
     env = dict(os.environ)
+    # the forced devices are host devices: pin the child to the CPU so it
+    # never reaches for an accelerator the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(_ROOT, "src"), _ROOT,

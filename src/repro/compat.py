@@ -1,62 +1,21 @@
-"""Version-compatibility shims over the JAX API surface.
-
-The codebase is written against the current JAX names
-(``jax.sharding.AxisType``, ``pallas.tpu.CompilerParams``); older jaxlib
-wheels (0.4.x) spell these differently or not at all.  Everything that
-touches a drifting name goes through this module so the same source runs
-on both — and so the next rename is a one-line fix here instead of an
-AttributeError cluster across kernels, launch and tests.
-"""
+"""Thin helpers over JAX API defaults the codebase relies on."""
 from __future__ import annotations
 
-import inspect
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them.
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
 
-    JAX >= 0.5 takes ``axis_types=(AxisType.Auto, ...)``; 0.4.x has
-    neither the kwarg nor the enum (every axis is implicitly Auto there,
-    so omitting it is semantically identical).
+    ``jax.make_mesh`` defaults to Explicit axes; the sharding rules,
+    ``shard_map`` paths and GSPMD-partitioned steps here are written for
+    Auto (sharding constraints, not sharding-in-types).
     """
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if (axis_type is not None
-            and "axis_types" in inspect.signature(jax.make_mesh).parameters):
-        kw["axis_types"] = (axis_type.Auto,) * len(tuple(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
-
-
-def cost_analysis(compiled) -> dict:
-    """Per-program XLA cost analysis as a flat dict.
-
-    ``Compiled.cost_analysis()`` returns a dict on current JAX but a
-    one-dict-per-device LIST on 0.4.x; normalize to the dict form.
-    """
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
-
-def tpu_compiler_params(*, dimension_semantics: Optional[tuple] = None,
-                        **kwargs):
-    """Pallas-TPU compiler params across the CompilerParams rename.
-
-    ``pltpu.CompilerParams`` (current) vs ``pltpu.TPUCompilerParams``
-    (jax 0.4.x) — identical fields, different class name.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    if dimension_semantics is not None:
-        kwargs["dimension_semantics"] = dimension_semantics
-    return cls(**kwargs)
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
+        **kw)

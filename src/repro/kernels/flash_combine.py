@@ -6,40 +6,38 @@ buffer; on TPU it is a small deterministic reduction kernel — grid over
 them with the LSE algebra in f32, and writes one normalized output tile.
 Bitwise-reproducible for any split count (the fixed reduction order).
 
-The ``ops``-level decode path uses the jnp combine (XLA fuses it well);
-this kernel exists for the TPU-native pipeline where the partials never
-round-trip through f32 HBM tensors owned by XLA — and as the reference
-for the VMEM budget note in DESIGN.md.
+``ops.decode_attention(impl="pallas")`` runs it after
+:func:`~repro.kernels.flash_decode.flash_decode_partials`; the xla impl
+merges with the jnp :func:`~repro.kernels.ref.lse_combine` instead.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.kernels import resolve_interpret
 
-from repro.kernels.ref import NEG_INF
+LANES = 128
 
 
 def _combine_kernel(acc_ref,          # (S, 1, 1, G, D) f32
                     l_ref,            # (S, 1, 1, G, LANES) f32
                     m_ref,            # (S, 1, 1, G, LANES) f32
-                    o_ref,            # (1, 1, G, D)
-                    *, num_splits: int):
-    acc = acc_ref[:, 0, 0]                       # (S, G, D)
-    l = l_ref[:, 0, 0, :, 0]                     # (S, G)
-    m = m_ref[:, 0, 0, :, 0]                     # (S, G)
+                    o_ref):           # (1, 1, G, D)
+    # the stats stay lane-replicated through the reduction: Mosaic has no
+    # lowering for a reduction over a lane-0 slice, and keeping the lane
+    # dim lets the weights broadcast across D as a (.., 1) column
+    acc = acc_ref[:, 0, 0]                                 # (S, G, D)
+    l = l_ref[:, 0, 0]                                     # (S, G, LANES)
+    m = m_ref[:, 0, 0]
 
-    m_glob = jnp.max(m, axis=0)                  # (G,)
-    w = jnp.exp(m - m_glob[None])                # (S, G)
-    num = jnp.sum(acc * w[..., None], axis=0)    # (G, D)
-    den = jnp.sum(l * w, axis=0)                 # (G,)
-    out = num / jnp.maximum(den[:, None], 1e-30)
-    o_ref[0, 0] = out.astype(o_ref.dtype)
+    m_glob = jnp.max(m, axis=0, keepdims=True)             # (1, G, LANES)
+    w = jnp.exp(m - m_glob)                                # (S, G, LANES)
+    num = jnp.sum(acc * w[..., :1], axis=0)                # (G, D)
+    den = jnp.sum(l * w, axis=0)[:, :1]                    # (G, 1)
+    o_ref[0, 0] = (num / jnp.maximum(den, 1e-30)).astype(o_ref.dtype)
 
 
 def flash_combine(
@@ -48,18 +46,19 @@ def flash_combine(
     m: jax.Array,            # (S, B, Hkv, G) f32
     *,
     out_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret=None,
 ) -> jax.Array:
-    """-> (B, Hkv, G, D) normalized attention output."""
+    """-> (B, Hkv, G, D) normalized attention output.
+
+    ``interpret`` resolves through :func:`repro.kernels.resolve_interpret`.
+    """
     S, B, Hkv, G, D = acc.shape
-    LANES = 128
     # stats lane-replicated for TPU layout (same trick as flash_decode)
     l_r = jnp.broadcast_to(l[..., None], (S, B, Hkv, G, LANES))
     m_r = jnp.broadcast_to(m[..., None], (S, B, Hkv, G, LANES))
 
-    kernel = functools.partial(_combine_kernel, num_splits=S)
-    out = pl.pallas_call(
-        kernel,
+    return pl.pallas_call(
+        _combine_kernel,
         grid=(B, Hkv),
         in_specs=[
             pl.BlockSpec((S, 1, 1, G, D), lambda b, h: (0, b, h, 0, 0)),
@@ -70,9 +69,8 @@ def flash_combine(
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name=f"flash_combine_s{S}",
     )(acc, l_r, m_r)
-    return out

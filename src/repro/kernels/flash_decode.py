@@ -1,38 +1,44 @@
 """Split-KV Flash-Decoding Pallas TPU kernel (the paper's target kernel).
 
-Grid layout ``(B, H_KV, S, NB)``:
+Grid layout ``(B, S, NB)``:
 
-- ``B, H_KV, S`` are *parallel* dimensions — the work tiles the scheduler
+- ``B, S`` are *parallel* dimensions — the work tiles the scheduler
   distributes; ``S`` is the sequence-split axis the paper's policy sizes.
 - ``NB`` (KV blocks within one split) is the innermost *arbitrary*
   dimension: a float32 running-softmax state lives in VMEM scratch and is
   carried across NB steps (classic flash accumulation).
 
+The cache keeps the engine's ``(B, L, H_KV, D)`` layout.  A K/V block is
+``(1, BK, H_KV, D)``: it spans the whole KV-head dim, which Mosaic
+accepts for any head count because the block's last two dims equal the
+array's, and one DMA brings every head's rows of the block.  The kernel
+walks the heads in a static loop, so each KV byte is read once per step.
+
 GQA packing (the paper's ``pack_gqa=True``): the ``G = H_Q/H_KV`` query
 heads of one group ride the MXU M-dimension as a single ``(G, D) @ (D, BK)``
 matmul — one tile per (batch, kv-head) instead of G.
 
-Each (b, h, s) cell emits an *unnormalized* partial ``(acc, l, m)``; a
-separate LSE-combine stage merges the S partials.  On GPU FA3 this combine
-uses atomics/semaphores; on TPU it is a deterministic reduction — decode
-results are bitwise-reproducible for any split count (tested).
+Each (b, s) cell emits an *unnormalized* partial ``(acc, l, m)`` per KV
+head; a separate LSE-combine stage merges the S partials.  On GPU FA3
+this combine uses atomics/semaphores; on TPU it is a deterministic
+reduction — decode results are bitwise-reproducible for any split count
+(tested).
 
-VMEM budget per grid cell (bf16 K/V, f32 state):
-``2*BK*D*2 + G*D*4 + 2*G*128*4 + G*D*4`` — for BK=128, D=128, G=8:
-~70 KiB, far under the ~1 MiB/cell needed to double-buffer in 128 MiB VMEM.
+VMEM per grid cell at qwen2.5-3b widths (BK=128, H_KV=2, G=8, D=128,
+bf16 K/V, f32 state): two double-buffered K/V blocks, whose 2-row head
+dim pads to a full 16-row bf16 tile (4 x 512 KiB), plus ~40 KiB of
+state — inside the default scoped VMEM limit.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
+from repro.kernels import resolve_interpret
 from repro.kernels.ref import NEG_INF
 
 DEFAULT_BLOCK_K = 128
@@ -40,27 +46,36 @@ STATS_LANES = 128            # stats stored lane-replicated for TPU layout
 
 
 def _decode_kernel(
-    # scalar prefetch
-    kv_len_ref,              # (B,) int32 in SMEM
-    # inputs
-    q_ref,                   # (1, 1, G, D)      — pre-scaled f32/bf16
-    k_ref,                   # (1, BK, 1, D)
-    v_ref,                   # (1, BK, 1, D)
-    # outputs
-    acc_out_ref,             # (1, 1, 1, G, D)   f32 unnormalized partial
-    l_out_ref,               # (1, 1, 1, G, STATS_LANES) f32
-    m_out_ref,               # (1, 1, 1, G, STATS_LANES) f32
-    # scratch
-    m_scr,                   # (G, STATS_LANES) f32
-    l_scr,                   # (G, STATS_LANES) f32
-    acc_scr,                 # (G, D) f32
-    *,
+    kv_len_ref,              # scalar prefetch: (B,) int32 in SMEM
+    q_ref,                   # (1, Hkv, G, D) — pre-scaled f32/bf16
+    k_ref,                   # (1, BK, Hkv, D) bf16 / f32 / int8 / fp8
+    v_ref,                   # (1, BK, Hkv, D)
+    *rest,
     num_blocks_per_split: int,
     block_k: int,
+    quantized: bool,
 ):
+    """Flash accumulation of one KV block for every KV head.
+
+    With ``quantized`` two more inputs follow ``v_ref``: the per-(row,
+    head) f32 scales ``(1, BK, Hkv)`` of K and V, applied in-register
+    (the same transform as ``Quantizer.dequantize``) — HBM streams one
+    byte per element plus a ``4/D`` fraction of scale bytes, and the rest
+    of the accumulation is identical to attending the dequantized arrays.
+    Scales of unallocated tail rows are masked by the same ``pos <
+    kv_len`` predicate as the data, so poisoned (finite) page tails never
+    reach the output.
+
+    Outputs ``(1, 1, Hkv, G, D)`` acc and ``(1, 1, Hkv, G, LANES)`` l/m;
+    scratch ``(Hkv, G, LANES)`` m/l and ``(Hkv, G, D)`` acc.
+    """
+    if quantized:
+        ks_ref, vs_ref, *rest = rest
+    acc_out_ref, l_out_ref, m_out_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    s = pl.program_id(2)
-    nb = pl.program_id(3)
+    s = pl.program_id(1)
+    nb = pl.program_id(2)
+    num_kv_heads, group = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(nb == 0)
     def _init():
@@ -68,41 +83,116 @@ def _decode_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)                    # (G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)              # (BK, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (G, BK)
-
     # mask cache positions beyond the valid length
     blk_idx = s * num_blocks_per_split + nb
     pos = blk_idx * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, 1)                        # (G, BK)
+        jnp.int32, (group, block_k), 1)                    # (G, BK)
     valid = pos < kv_len_ref[b]
-    scores = jnp.where(valid, scores, NEG_INF)
+    if quantized:
+        k_sc = ks_ref[0]                                   # (BK, Hkv)
+        v_sc = vs_ref[0]
 
-    m_prev = m_scr[:, :1]                                  # (G, 1)
-    m_cur = jnp.max(scores, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(scores - m_new)
-    p = jnp.where(valid, p, 0.0)                           # kill exp(-inf - -inf)
-    alpha = jnp.exp(m_prev - m_new)                        # (G, 1)
+    for h in range(num_kv_heads):
+        q = q_ref[0, h].astype(jnp.float32)                # (G, D)
+        k = k_ref[0, :, h, :].astype(jnp.float32)          # (BK, D)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        if quantized:
+            k = k * k_sc[:, h:h + 1]
+            v = v * v_sc[:, h:h + 1]
 
-    l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # (G, BK)
+        scores = jnp.where(valid, scores, NEG_INF)
 
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_prev = m_scr[h, :, :1]                           # (G, 1)
+        m_cur = jnp.max(scores, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(scores - m_new)
+        p = jnp.where(valid, p, 0.0)                       # kill exp(-inf - -inf)
+        alpha = jnp.exp(m_prev - m_new)                    # (G, 1)
+
+        l_new = l_scr[h, :, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+        l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     @pl.when(nb == num_blocks_per_split - 1)
     def _flush():
-        acc_out_ref[0, 0, 0] = acc_scr[...]
-        l_out_ref[0, 0, 0] = l_scr[...]
-        m_out_ref[0, 0, 0] = m_scr[...]
+        acc_out_ref[0, 0] = acc_scr[...]
+        l_out_ref[0, 0] = l_scr[...]
+        m_out_ref[0, 0] = m_scr[...]
+
+
+def _split_kv_call(q, k, v, scales, kv_len, *, num_splits: int,
+                   block_k: int, interpret, name: str):
+    """Shared ``pallas_call`` of the bf16 and quantized kernels.
+
+    ``scales`` is ``()`` or ``(k_scale, v_scale)``.  Returns ``(acc, l,
+    m)`` in the ``(S, B, Hkv, G, ...)`` layout of
+    :func:`repro.kernels.ref.lse_combine`."""
+    B, Hkv, G, D = q.shape
+    _, L, _, _ = k.shape
+    S = num_splits
+    assert L % block_k == 0, f"pad L ({L}) to block_k ({block_k})"
+    nblk = L // block_k
+    assert nblk % S == 0, f"pad blocks ({nblk}) to splits ({S})"
+    NB = nblk // S
+
+    kernel = functools.partial(
+        _decode_kernel, num_blocks_per_split=NB, block_k=block_k,
+        quantized=bool(scales))
+
+    def kv_block(b, s, nb, kvl):
+        return (b, s * NB + nb, 0, 0)
+
+    def scale_block(b, s, nb, kvl):
+        return (b, s * NB + nb, 0)
+
+    def part_block(b, s, nb, kvl):
+        return (b, s, 0, 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, Hkv, G, D), lambda b, s, nb, kvl: (b, 0, 0, 0)),
+        pl.BlockSpec((1, block_k, Hkv, D), kv_block),
+        pl.BlockSpec((1, block_k, Hkv, D), kv_block),
+    ] + [pl.BlockSpec((1, block_k, Hkv), scale_block)] * len(scales)
+    acc, l, m = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, S, NB),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, Hkv, G, D), part_block),
+                pl.BlockSpec((1, 1, Hkv, G, STATS_LANES), part_block),
+                pl.BlockSpec((1, 1, Hkv, G, STATS_LANES), part_block),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((Hkv, G, STATS_LANES), jnp.float32),
+                pltpu.VMEM((Hkv, G, STATS_LANES), jnp.float32),
+                pltpu.VMEM((Hkv, G, D), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, S, Hkv, G, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, S, Hkv, G, STATS_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, S, Hkv, G, STATS_LANES), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
+        name=f"{name}_s{S}",
+    )(kv_len.astype(jnp.int32), q, k, v,
+      *(x.astype(jnp.float32) for x in scales))
+
+    # -> (S, B, Hkv, G, ...) layout expected by lse_combine
+    acc = acc.transpose(1, 0, 2, 3, 4)
+    l = l[..., 0].transpose(1, 0, 2, 3)
+    m = m[..., 0].transpose(1, 0, 2, 3)
+    return acc, l, m
 
 
 def flash_decode_partials(
@@ -113,154 +203,17 @@ def flash_decode_partials(
     *,
     num_splits: int,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret=None,
 ):
     """Run the split-KV kernel; returns unnormalized partials.
 
     Returns ``(acc, l, m)`` with shapes ``(S,B,Hkv,G,D)``, ``(S,B,Hkv,G)``,
     ``(S,B,Hkv,G)`` matching :func:`repro.kernels.ref.lse_combine`.
+    ``interpret`` resolves through :func:`repro.kernels.resolve_interpret`.
     """
-    B, Hkv, G, D = q.shape
-    _, L, _, _ = k.shape
-    S = num_splits
-    assert L % block_k == 0, f"pad L ({L}) to block_k ({block_k})"
-    nblk = L // block_k
-    assert nblk % S == 0, f"pad blocks ({nblk}) to splits ({S})"
-    NB = nblk // S
-
-    kernel = functools.partial(
-        _decode_kernel, num_blocks_per_split=NB, block_k=block_k)
-
-    grid = (B, Hkv, S, NB)
-    acc, l, m = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, G, D), lambda b, h, s, nb, kvl: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, D),
-                             lambda b, h, s, nb, kvl: (b, s * NB + nb, h, 0)),
-                pl.BlockSpec((1, block_k, 1, D),
-                             lambda b, h, s, nb, kvl: (b, s * NB + nb, h, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, 1, G, D),
-                             lambda b, h, s, nb, kvl: (b, h, s, 0, 0)),
-                pl.BlockSpec((1, 1, 1, G, STATS_LANES),
-                             lambda b, h, s, nb, kvl: (b, h, s, 0, 0)),
-                pl.BlockSpec((1, 1, 1, G, STATS_LANES),
-                             lambda b, h, s, nb, kvl: (b, h, s, 0, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((G, STATS_LANES), jnp.float32),
-                pltpu.VMEM((G, STATS_LANES), jnp.float32),
-                pltpu.VMEM((G, D), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, S, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, S, G, STATS_LANES), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, S, G, STATS_LANES), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
-        interpret=interpret,
-        name=f"flash_decode_s{S}",
-    )(kv_len.astype(jnp.int32), q, k, v)
-
-    # -> (S, B, Hkv, G, ...) layout expected by lse_combine
-    acc = acc.transpose(2, 0, 1, 3, 4)
-    l = l[..., 0].transpose(2, 0, 1, 3)
-    m = m[..., 0].transpose(2, 0, 1, 3)
-    return acc, l, m
-
-
-# ---------------------------------------------------------------------------
-# Fused low-precision variant: int8/fp8 KV blocks dequantized in-register
-# ---------------------------------------------------------------------------
-
-
-def _decode_quant_kernel(
-    # scalar prefetch
-    kv_len_ref,              # (B,) int32 in SMEM
-    # inputs
-    q_ref,                   # (1, 1, G, D)      — pre-scaled f32/bf16
-    k_ref,                   # (1, BK, 1, D)     int8 / float8_e4m3fn
-    v_ref,                   # (1, BK, 1, D)     int8 / float8_e4m3fn
-    ks_ref,                  # (1, BK, 1) f32    per-(row, head) scales
-    vs_ref,                  # (1, BK, 1) f32
-    # outputs
-    acc_out_ref,             # (1, 1, 1, G, D)   f32 unnormalized partial
-    l_out_ref,               # (1, 1, 1, G, STATS_LANES) f32
-    m_out_ref,               # (1, 1, 1, G, STATS_LANES) f32
-    # scratch
-    m_scr,                   # (G, STATS_LANES) f32
-    l_scr,                   # (G, STATS_LANES) f32
-    acc_scr,                 # (G, D) f32
-    *,
-    num_blocks_per_split: int,
-    block_k: int,
-):
-    """:func:`_decode_kernel` with in-register dequant of quantized KV.
-
-    The ONLY difference from the bf16 kernel is the two
-    ``astype(f32) * scale`` lines — HBM streams 1 byte/element plus a
-    4-byte scale per (row, head) (a ``4/D`` fraction, ~3% at D=128), and
-    the rest of the flash accumulation is bit-identical to attending the
-    dequantized arrays.  Scales of unallocated tail rows are masked by
-    the same ``pos < kv_len`` predicate as the data, so poisoned (finite)
-    page tails never reach the output.
-    """
-    b = pl.program_id(0)
-    s = pl.program_id(2)
-    nb = pl.program_id(3)
-
-    @pl.when(nb == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0, 0].astype(jnp.float32)                    # (G, D)
-    ks = ks_ref[0, :, 0]                                   # (BK,)
-    vs = vs_ref[0, :, 0]
-    # in-register dequant: same transform as Quantizer.dequantize
-    k = k_ref[0, :, 0, :].astype(jnp.float32) * ks[:, None]  # (BK, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32) * vs[:, None]
-
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (G, BK)
-
-    blk_idx = s * num_blocks_per_split + nb
-    pos = blk_idx * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, 1)                        # (G, BK)
-    valid = pos < kv_len_ref[b]
-    scores = jnp.where(valid, scores, NEG_INF)
-
-    m_prev = m_scr[:, :1]                                  # (G, 1)
-    m_cur = jnp.max(scores, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(scores - m_new)
-    p = jnp.where(valid, p, 0.0)                           # kill exp(-inf - -inf)
-    alpha = jnp.exp(m_prev - m_new)                        # (G, 1)
-
-    l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(nb == num_blocks_per_split - 1)
-    def _flush():
-        acc_out_ref[0, 0, 0] = acc_scr[...]
-        l_out_ref[0, 0, 0] = l_scr[...]
-        m_out_ref[0, 0, 0] = m_scr[...]
+    return _split_kv_call(q, k, v, (), kv_len, num_splits=num_splits,
+                          block_k=block_k, interpret=interpret,
+                          name="flash_decode")
 
 
 def flash_decode_quant_partials(
@@ -273,7 +226,7 @@ def flash_decode_quant_partials(
     *,
     num_splits: int,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret=None,
 ):
     """Split-KV kernel over a quantized cache; unnormalized partials.
 
@@ -281,63 +234,6 @@ def flash_decode_quant_partials(
     :func:`flash_decode_partials`; K/V blocks arrive in storage dtype and
     are dequantized in-register against their per-row scale blocks.
     """
-    B, Hkv, G, D = q.shape
-    _, L, _, _ = k.shape
-    S = num_splits
-    assert L % block_k == 0, f"pad L ({L}) to block_k ({block_k})"
-    nblk = L // block_k
-    assert nblk % S == 0, f"pad blocks ({nblk}) to splits ({S})"
-    NB = nblk // S
-
-    kernel = functools.partial(
-        _decode_quant_kernel, num_blocks_per_split=NB, block_k=block_k)
-
-    grid = (B, Hkv, S, NB)
-    acc, l, m = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, G, D), lambda b, h, s, nb, kvl: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, D),
-                             lambda b, h, s, nb, kvl: (b, s * NB + nb, h, 0)),
-                pl.BlockSpec((1, block_k, 1, D),
-                             lambda b, h, s, nb, kvl: (b, s * NB + nb, h, 0)),
-                pl.BlockSpec((1, block_k, 1),
-                             lambda b, h, s, nb, kvl: (b, s * NB + nb, h)),
-                pl.BlockSpec((1, block_k, 1),
-                             lambda b, h, s, nb, kvl: (b, s * NB + nb, h)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, 1, G, D),
-                             lambda b, h, s, nb, kvl: (b, h, s, 0, 0)),
-                pl.BlockSpec((1, 1, 1, G, STATS_LANES),
-                             lambda b, h, s, nb, kvl: (b, h, s, 0, 0)),
-                pl.BlockSpec((1, 1, 1, G, STATS_LANES),
-                             lambda b, h, s, nb, kvl: (b, h, s, 0, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((G, STATS_LANES), jnp.float32),
-                pltpu.VMEM((G, STATS_LANES), jnp.float32),
-                pltpu.VMEM((G, D), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, S, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, S, G, STATS_LANES), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, S, G, STATS_LANES), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
-        interpret=interpret,
-        name=f"flash_decode_quant_s{S}",
-    )(kv_len.astype(jnp.int32), q, k, v,
-      k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
-
-    acc = acc.transpose(2, 0, 1, 3, 4)
-    l = l[..., 0].transpose(2, 0, 1, 3)
-    m = m[..., 0].transpose(2, 0, 1, 3)
-    return acc, l, m
+    return _split_kv_call(q, k, v, (k_scale, v_scale), kv_len,
+                          num_splits=num_splits, block_k=block_k,
+                          interpret=interpret, name="flash_decode_quant")

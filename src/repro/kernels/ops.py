@@ -5,8 +5,11 @@ Two execution paths per op, selected by ``impl``:
 - ``"xla"``    — the pure-jnp reference implementations from ``ref.py``.
   Cost-analyzable, differentiable, shardable under pjit; the default for
   train/dry-run (on this CPU container it is also the fast path).
-- ``"pallas"`` — the Pallas TPU kernels (``interpret=True`` on CPU).  The
-  TPU-native hot path; numerics validated against ``ref.py`` in tests.
+- ``"pallas"`` — the Pallas TPU kernels: compiled by Mosaic on a TPU,
+  run by the Pallas interpreter on the CPU backend
+  (:func:`repro.kernels.resolve_interpret`; ``interpret=None`` everywhere
+  by default).  The TPU-native hot path; numerics validated against
+  ``ref.py`` in tests.
 
 ``decode_attention`` is the op the paper targets: its split count comes
 from a frozen :class:`~repro.plan.LaunchPlan` (the paper's
@@ -32,7 +35,10 @@ import jax.numpy as jnp
 
 from repro.core.scheduler_metadata import get_scheduler_metadata
 from repro.kernels import ref
-from repro.kernels.flash_decode import flash_decode_partials
+from repro.kernels.flash_combine import flash_combine
+from repro.kernels.flash_decode import (DEFAULT_BLOCK_K,
+                                        flash_decode_partials,
+                                        flash_decode_quant_partials)
 from repro.kernels.flash_prefill import flash_prefill
 from repro.plan import LaunchPlan, current_plan, plan_scope
 
@@ -235,7 +241,7 @@ def attention(
     window: Optional[int] = None,
     q_offset: int | jax.Array = 0,
     impl: str = "xla",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Full (training / prefill) attention.
 
@@ -271,7 +277,6 @@ def _attention_seqpar(q, k, v, *, causal, window, q_offset, mesh,
                       axis: str, impl: str = "xla") -> jax.Array:
     """Sequence-parallel blocked attention: q rows sharded over ``axis``,
     each chip runs local flash on its chunk with the global offset."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, Lq, Hq, D = q.shape
@@ -291,13 +296,13 @@ def _attention_seqpar(q, k, v, *, causal, window, q_offset, mesh,
         return ref.flash_attention_xla(qc, kf, vf, causal=causal,
                                        window=window, q_offset=off)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, axis, None, None),
                   P(bspec, None, None, None),
                   P(bspec, None, None, None)),
         out_specs=P(bspec, axis, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k, v)
 
 
@@ -320,7 +325,7 @@ def decode_attention(
     policy: str = _DEFAULT_POLICY,
     num_cores: Optional[int] = None,
     impl: str = "xla",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     scale: Optional[float] = None,
 ) -> jax.Array:
     """Split-KV decode attention, split count from the paper's policy.
@@ -509,6 +514,7 @@ def decode_attention_update(
     policy: str = _DEFAULT_POLICY,
     num_cores: Optional[int] = None,
     impl: Optional[str] = None,     # None = xla (a plan's impl overrides)
+    interpret: Optional[bool] = None,
     quant: Optional[dict] = None,   # quantized cache: {"k_s","v_s","k_ns","v_ns"}
 ) -> tuple:
     """Fused cache-write + split decode attention.
@@ -517,8 +523,9 @@ def decode_attention_update(
     decides the collectives).  When the ambient plan has
     ``seq_shard_mesh``, the fused shard_map path runs instead: each chip
     writes only its own cache shard and computes a partial softmax over
-    it; partials merge with a psum/pmax LSE combine — the paper's
-    split-KV combine as explicit mesh collectives.
+    it; the chips all-gather their partials and merge them with
+    :func:`ref.lse_combine` — the paper's split-KV combine as explicit
+    mesh collectives, in the one-device combine's order.
 
     Returns (out (B, Hq, Dv), new_cache_k, new_cache_v).
     """
@@ -561,14 +568,14 @@ def decode_attention_update(
                                scale=scale, plan=plan,
                                use_ctx_metadata=use_ctx_metadata,
                                policy=policy, num_cores=num_cores,
-                               impl=impl or "xla")
+                               impl=impl or "xla", interpret=interpret)
         return out, cache_k, cache_v, k_s, v_s
     v_used = cache_v if cache_v is not None else cache_k[..., :v_width]
     out = decode_attention(q, cache_k, v_used, kv_len, scale=scale,
                            plan=plan,
                            use_ctx_metadata=use_ctx_metadata,
                            policy=policy, num_cores=num_cores,
-                           impl=impl or "xla")
+                           impl=impl or "xla", interpret=interpret)
     return out, cache_k, cache_v
 
 
@@ -576,7 +583,6 @@ def _decode_seqsharded(q, cache_k, cache_v, k_new, v_new, t, kv_len, *,
                        mesh, axis: str, v_width: Optional[int],
                        scale: Optional[float],
                        quant: Optional[dict] = None) -> tuple:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, Hq, Dq = q.shape
@@ -607,11 +613,14 @@ def _decode_seqsharded(q, cache_k, cache_v, k_new, v_new, t, kv_len, *,
         pos = i * C + jnp.arange(C)                       # global positions
         valid = pos[None, :] < lenb[:, None]              # (B_loc, C)
         acc, l, m = ref.decode_partial(qf, kf, vf, valid)
-        m_glob = jax.lax.pmax(m, axis)
-        w = jnp.exp(m - m_glob)
-        num = jax.lax.psum(acc * w[..., None], axis)
-        den = jax.lax.psum(l * w, axis)
-        out = num / jnp.maximum(den[..., None], 1e-30)
+        # gather the n chips' partials and merge them with the one-device
+        # LSE combine: the merge then sums in the order of
+        # ref.split_decode_xla at S = n, so a sequence-sharded decode
+        # reproduces the one-chip split decode instead of re-associating
+        # its f32 sums (a psum's order is the collective's choice)
+        out = ref.lse_combine(jax.lax.all_gather(acc, axis),
+                              jax.lax.all_gather(l, axis),
+                              jax.lax.all_gather(m, axis))
         Dv = out.shape[-1]
         return out.reshape(-1, Hq, Dv).astype(qb.dtype)
 
@@ -646,34 +655,34 @@ def _decode_seqsharded(q, cache_k, cache_v, k_new, v_new, t, kv_len, *,
         return core(qb, kf, vf, lenb, i), kc, vc, ksc, vsc
 
     if quant is not None:
-        fn = shard_map(
+        fn = jax.shard_map(
             body_q, mesh=mesh,
             in_specs=(vec_spec, cache_spec, cache_spec, sc_spec, sc_spec,
                       vec_spec, vec_spec, hvec_spec, hvec_spec,
                       scal_spec, scal_spec),
             out_specs=(vec_spec, cache_spec, cache_spec, sc_spec, sc_spec),
-            check_rep=False)
+            check_vma=False)
         return fn(q, cache_k, cache_v, quant["k_s"], quant["v_s"],
                   k_new, v_new, quant["k_ns"], quant["v_ns"], t, kv_len)
 
     if cache_v is not None:
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(vec_spec, cache_spec, cache_spec, vec_spec,
                       vec_spec, scal_spec, scal_spec),
             out_specs=(vec_spec, cache_spec, cache_spec),
-            check_rep=False)
+            check_vma=False)
         return fn(q, cache_k, cache_v, k_new, v_new, t, kv_len)
 
     def body_nov(qb, kc, kn, tb, lenb):
         o, ck, _ = body(qb, kc, None, kn, None, tb, lenb)
         return o, ck
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body_nov, mesh=mesh,
         in_specs=(vec_spec, cache_spec, vec_spec, scal_spec, scal_spec),
         out_specs=(vec_spec, cache_spec),
-        check_rep=False)
+        check_vma=False)
     out, ck = fn(q, cache_k, k_new, t, kv_len)
     return out, ck, None
 
@@ -687,10 +696,8 @@ def _prod(it) -> int:
 
 def _decode_pallas(q, k, v, kv_len, *, num_splits: int,
                    block_k: Optional[int] = None,
-                   interpret: bool) -> jax.Array:
+                   interpret: Optional[bool]) -> jax.Array:
     """GQA-pack, pad, run the Pallas split kernel, LSE-combine."""
-    from repro.kernels.flash_decode import DEFAULT_BLOCK_K
-
     B, Hq, D = q.shape
     _, Lk, Hkv, _ = k.shape
     g = Hq // Hkv
@@ -707,23 +714,19 @@ def _decode_pallas(q, k, v, kv_len, *, num_splits: int,
         v = jnp.pad(v, ((0, 0), (0, Lp - Lk), (0, 0), (0, 0)))
 
     acc, l, m = flash_decode_partials(
-        qp.astype(q.dtype), k, v, kv_len, num_splits=num_splits,
+        qp, k, v, kv_len, num_splits=num_splits,
         block_k=block_k, interpret=interpret)
-    from repro.kernels.flash_combine import flash_combine
     out = flash_combine(acc, l, m, interpret=interpret)  # (B, Hkv, g, D)
     return out.reshape(B, Hq, D).astype(q.dtype)
 
 
 def _decode_pallas_quant(q, k, v, k_scale, v_scale, kv_len, *,
                          num_splits: int, block_k: Optional[int] = None,
-                         interpret: bool) -> jax.Array:
+                         interpret: Optional[bool]) -> jax.Array:
     """Quantized-cache twin of :func:`_decode_pallas`: GQA-pack, pad the
     storage-dtype cache AND its scale leaves, run the fused in-register
     dequant kernel, LSE-combine.  Padded tail rows carry zero scales but
     are masked by ``kv_len`` regardless (the repo-wide invariant)."""
-    from repro.kernels.flash_decode import (DEFAULT_BLOCK_K,
-                                            flash_decode_quant_partials)
-
     B, Hq, D = q.shape
     _, Lk, Hkv, _ = k.shape
     g = Hq // Hkv
@@ -742,8 +745,7 @@ def _decode_pallas_quant(q, k, v, k_scale, v_scale, kv_len, *,
         v_scale = jnp.pad(v_scale, pad4[:3])
 
     acc, l, m = flash_decode_quant_partials(
-        qp.astype(q.dtype), k, v, k_scale, v_scale, kv_len,
+        qp, k, v, k_scale, v_scale, kv_len,
         num_splits=num_splits, block_k=block_k, interpret=interpret)
-    from repro.kernels.flash_combine import flash_combine
     out = flash_combine(acc, l, m, interpret=interpret)  # (B, Hkv, g, D)
     return out.reshape(B, Hq, D).astype(q.dtype)

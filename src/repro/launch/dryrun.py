@@ -30,7 +30,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import SHAPES, get_arch, list_archs, shape_applicable
 from repro.configs.base import (
     OptimizerConfig,
@@ -117,7 +116,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     except Exception as e:                                # pragma: no cover
         rec["memory_analysis"] = {"error": str(e)}
 
-    cost = compat.cost_analysis(compiled)
+    cost = dict(compiled.cost_analysis() or {})
     rec["cost_analysis"] = {k: float(v) for k, v in cost.items()
                             if isinstance(v, (int, float))
                             and k in ("flops", "bytes accessed",

@@ -1,27 +1,31 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> ...``
 
-Loads (or random-inits) a reduced model and drives the request-lifecycle
+Random-inits a model and drives the request-lifecycle
 :class:`~repro.serving.ServingEngine` (submit/step/stream/drain) over a
 synthetic request stream, printing per-request completions and aggregate
-TPOT.  ``--policy`` A/Bs the paper's heuristic against the flawed
-baseline on the same requests; ``--temperature/--top-k/--top-p`` select
-seeded sampling (default greedy); ``--stream`` prints TOKEN/FINISHED
-events as the engine emits them; ``--prefill`` switches between fused
-bucketed admission and the legacy teacher-forcing loop.
+TPOT.  The model is reduced (``--d-model``/``--layers``, CPU-sized) unless
+``--full-width`` serves the registered config at its published widths;
+``--impl pallas`` serves through the Pallas kernels.  ``--policy`` A/Bs
+the paper's heuristic against the flawed baseline on the same requests;
+``--temperature/--top-k/--top-p`` select seeded sampling (default
+greedy); ``--stream`` prints TOKEN/FINISHED events as the engine emits
+them; ``--prefill`` switches between fused bucketed admission and the
+legacy teacher-forcing loop.
 """
 from __future__ import annotations
 
 import argparse
 import time
-from typing import List
+from typing import List, Tuple
 
 import jax
 import numpy as np
 
 from repro.configs import get_arch
-from repro.configs.base import ServeConfig
+from repro.configs.base import ModelConfig, ServeConfig
 from repro.configs.reduced import reduced_config
 from repro.core.split_policy import available_policies
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.serving import (
     FINISHED,
@@ -33,10 +37,63 @@ from repro.serving import (
 )
 
 
+def serving_config(arch: str, *, full_width: bool = False,
+                   d_model: int = 128, num_layers: int = 2,
+                   attention_impl: str = "xla") -> ModelConfig:
+    """The served model config: the registered one at its published
+    widths with ``full_width``, else ``reduced_config`` at ``d_model`` /
+    ``num_layers`` (CPU-sized)."""
+    cfg = get_arch(arch)
+    if not full_width:
+        cfg = reduced_config(cfg, num_layers=num_layers, d_model=d_model)
+    if cfg.family in ("vlm", "encdec"):
+        raise NotImplementedError(
+            "CLI serving drives text-only archs; frontend-stub archs are "
+            "exercised by the tests")
+    return cfg.replace(attention_impl=attention_impl)
+
+
+def build_engine(model, scfg: ServeConfig, *, batch_slots: int,
+                 max_len: int, sampler: str = "categorical", mesh=None):
+    """The launcher's engine: one :class:`ServingEngine`, or with
+    ``mesh`` ("dp,sp") a :class:`~repro.shard.ShardedServingEngine` whose
+    ``batch_slots`` are slots PER SHARD."""
+    if mesh:
+        from repro.shard import ShardedServingEngine, ShardSpec
+        spec = ShardSpec.parse(mesh, slots_per_shard=batch_slots)
+        return ShardedServingEngine(model, scfg, spec=spec, max_len=max_len,
+                                    sampler=get_sampler(sampler))
+    return ServingEngine(model, scfg, max_len=max_len,
+                         batch_slots=batch_slots,
+                         sampler=get_sampler(sampler))
+
+
+def synthetic_requests(cfg: ModelConfig, num_requests: int, max_new: int, *,
+                       seed: int = 0, prompt_len: Tuple[int, int] = (4, 12),
+                       shared_prefix: int = 0, temperature: float = 0.0,
+                       top_k: int = 0, top_p: float = 1.0) -> List[Request]:
+    """Seeded random-token requests: a ``shared_prefix``-token "system
+    prompt" common to all of them, then a unique tail of
+    ``prompt_len[0] <= n < prompt_len[1]`` tokens."""
+    rng = np.random.default_rng(seed)
+    system = rng.integers(0, cfg.vocab_size, size=shared_prefix).tolist() \
+        if shared_prefix else []
+    return [
+        Request(i, system
+                + rng.integers(0, cfg.vocab_size,
+                               size=rng.integers(*prompt_len)).tolist(),
+                max_new_tokens=max_new,
+                sampling=SamplingParams(temperature=temperature,
+                                        top_k=top_k, top_p=top_p,
+                                        seed=seed + i))
+        for i in range(num_requests)]
+
+
 def run_serving(arch: str, *, num_requests: int = 8, max_new: int = 16,
                 policy: str = "paper", batch_slots: int = 4,
                 max_len: int = 256, d_model: int = 128,
-                num_layers: int = 2, seed: int = 0,
+                num_layers: int = 2, full_width: bool = False,
+                attention_impl: str = "xla", seed: int = 0,
                 num_splits_override=None, temperature: float = 0.0,
                 top_k: int = 0, top_p: float = 1.0,
                 sampler: str = "categorical",
@@ -46,14 +103,11 @@ def run_serving(arch: str, *, num_requests: int = 8, max_new: int = 16,
                 speculate_max_rejects=None, kv_quant=None,
                 tune_table=None, stats_path=None, mesh=None,
                 trace_path=None, metrics_path=None, log_fn=print):
-    cfg = reduced_config(get_arch(arch), num_layers=num_layers,
-                         d_model=d_model)
-    if cfg.family in ("vlm", "encdec"):
-        raise NotImplementedError(
-            "CLI serving drives text-only archs; frontend-stub archs are "
-            "exercised by the tests")
+    cfg = serving_config(arch, full_width=full_width, d_model=d_model,
+                         num_layers=num_layers,
+                         attention_impl=attention_impl)
     model = build_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(seed))
+    params = jax.jit(model.init_params)(jax.random.PRNGKey(seed))
     scfg = ServeConfig(model=cfg, split_policy=policy,
                        num_splits_override=num_splits_override,
                        prefill_mode=prefill_mode,
@@ -72,34 +126,17 @@ def run_serving(arch: str, *, num_requests: int = 8, max_new: int = 16,
                        metrics_path=(str(metrics_path) if metrics_path
                                      else None),
                        shard=mesh)
-    if mesh:
-        # mesh-native topology: --slots becomes slots PER SHARD
-        from repro.shard import ShardedServingEngine, ShardSpec
-        spec = ShardSpec.parse(mesh, slots_per_shard=batch_slots)
-        engine = ShardedServingEngine(
-            model, scfg, spec=spec, max_len=max_len,
-            sampler=get_sampler(sampler))
-    else:
-        engine = ServingEngine(
-            model, scfg, max_len=max_len, batch_slots=batch_slots,
-            sampler=get_sampler(sampler))
+    engine = build_engine(model, scfg, batch_slots=batch_slots,
+                          max_len=max_len, sampler=sampler, mesh=mesh)
     engine.load(params)
 
-    rng = np.random.default_rng(seed)
     # --share-prefix traffic models the production shape the knob
     # exists for: every request opens with the same "system prompt"
     # (long enough to span full pages), then a short unique tail
-    system = (rng.integers(0, cfg.vocab_size, size=96).tolist()
-              if share_prefix else [])
-    reqs: List[Request] = [
-        Request(i, system
-                + rng.integers(0, cfg.vocab_size,
-                               size=rng.integers(4, 12)).tolist(),
-                max_new_tokens=max_new,
-                sampling=SamplingParams(temperature=temperature,
-                                        top_k=top_k, top_p=top_p,
-                                        seed=seed + i))
-        for i in range(num_requests)]
+    reqs = synthetic_requests(cfg, num_requests, max_new, seed=seed,
+                              shared_prefix=96 if share_prefix else 0,
+                              temperature=temperature, top_k=top_k,
+                              top_p=top_p)
     t0 = time.monotonic()
     handles = [engine.submit(r) for r in reqs]
     if stream:
@@ -200,6 +237,13 @@ def main() -> None:
                          "here at drain (.prom/.txt suffix selects "
                          "Prometheus text exposition, else JSON)")
     ap.add_argument("--slots", type=int, default=4)
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the registered config at its published "
+                         "widths (default: reduced to d_model 128, 2 "
+                         "layers, CPU-sized)")
+    ap.add_argument("--impl", default="xla", choices=("xla", "pallas"),
+                    help="attention impl: pure-jnp XLA or the Pallas "
+                         "kernels (compiled on a TPU, interpreted on CPU)")
     ap.add_argument("--splits", type=int, default=None,
                     help="explicit num_splits override: the engine's "
                          "Planner bypasses the policy (FA3's explicit "
@@ -249,9 +293,11 @@ def main() -> None:
     ap.add_argument("--stream", action="store_true",
                     help="print TOKEN/FINISHED events as they happen")
     args = ap.parse_args()
+    enable_compile_cache()
     run_serving(args.arch, num_requests=args.requests,
                 max_new=args.max_new, policy=args.policy,
-                batch_slots=args.slots,
+                batch_slots=args.slots, full_width=args.full_width,
+                attention_impl=args.impl,
                 num_splits_override=args.splits,
                 temperature=args.temperature, top_k=args.top_k,
                 top_p=args.top_p, sampler=args.sampler,

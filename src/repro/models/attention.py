@@ -1,12 +1,12 @@
 """Standard multi-head attention block (GQA/MQA/MHA) with KV cache.
 
-Three entry points, all operating on a single layer's params:
+Entry points, all operating on a single layer's params:
 
 - :func:`attention_train`   — full-sequence causal (optionally windowed)
   attention for training / prefill.
-- :func:`attention_decode`  — one-token decode against a padded KV cache,
-  routed through the paper's split policy via ``kernels.ops``.
-- :func:`cache_update`      — functional KV-cache write at position ``t``.
+- :func:`attention_decode`  — one-token cache write + decode against a
+  padded KV cache, routed through the paper's split policy via
+  ``kernels.ops``.
 
 Cache layout is ``(B, L_max, H_kv, D)`` — sequence-major so the mesh-level
 sequence split (serving/decode_step.py) can shard ``L_max`` directly.
@@ -23,6 +23,7 @@ from repro.kernels import ops
 from repro.models.common import ParamSpec, apply_rope
 from repro.plan import LaunchPlan
 from repro.quant import QUANT_DTYPES, Quantizer
+from repro.sharding.ctx import shard_activation
 
 Params = Dict[str, jax.Array]
 
@@ -103,6 +104,13 @@ def attention_prefill(
     if impl is None and plan is not None:
         impl = plan.impl
     q, k, v = _project_qkv(params, cfg, x, positions)
+    # the prompt's K/V rows stay whole on every chip of a mesh: without
+    # this anchor GSPMD carries a sequence-sharded cache's layout back
+    # into the attention and splits its key axis across chips, summing
+    # the softmax partials with an all-reduce (another association of
+    # the f32 sums than the one-device prefill)
+    k, v = (shard_activation(t, ("batch", None, "kv_heads", None))
+            for t in (k, v))
     out = ops.attention(q, k, v, causal=True, window=window,
                         impl=impl or cfg.attention_impl)
     y = jnp.einsum("blhk,hkd->bld", out, params["wo"])
@@ -332,27 +340,6 @@ def dequantize_kv(q: jax.Array, scale: jax.Array) -> jax.Array:
     return _INT8.dequantize(q, scale)
 
 
-def cache_update(cache: Dict[str, jax.Array], k_new: jax.Array,
-                 v_new: jax.Array, t: jax.Array) -> Dict[str, jax.Array]:
-    """Write one token's K/V at position t.
-
-    ``t``: scalar (lockstep decode) or (B,) (continuous batching — each
-    slot at its own position).
-    """
-    B = k_new.shape[0]
-    tv = jnp.broadcast_to(t.astype(jnp.int32), (B,))
-
-    def upd(c, new, ti):
-        return jax.lax.dynamic_update_slice(
-            c, new[None].astype(c.dtype),
-            (ti, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32)))
-
-    return {
-        "k": jax.vmap(upd)(cache["k"], k_new, tv),
-        "v": jax.vmap(upd)(cache["v"], v_new, tv),
-    }
-
-
 def attention_decode(
     params: Params,
     cfg: ModelConfig,
@@ -390,10 +377,9 @@ def attention_decode(
         write_t = tv
         kv_len = tv + 1
     if "k_s" in cache:                      # quantized KV cache (§Perf C.4)
-        # checked BEFORE the pallas branch: a raw cache_update would cast
-        # bf16 rows straight into the storage dtype (garbage without the
-        # scales).  impl="pallas" here means the fused in-register-
-        # dequant kernel, not the bf16 one.
+        # rows are quantized before the write (a raw write would cast bf16
+        # straight into the storage dtype).  impl="pallas" here means the
+        # fused in-register-dequant kernel, not the bf16 one.
         qz = Quantizer.for_cache(cache)
         kq, kns = qz.quantize(k_new[:, 0])
         vq, vns = qz.quantize(v_new[:, 0])
@@ -404,15 +390,11 @@ def attention_decode(
             quant={"k_s": cache["k_s"], "v_s": cache["v_s"],
                    "k_ns": kns, "v_ns": vns})
         cache = {"k": ck, "v": cv, "k_s": ks, "v_s": vs}
-    elif (impl or cfg.attention_impl) == "pallas":
-        cache = cache_update(cache, k_new[:, 0], v_new[:, 0], write_t)
-        out = ops.decode_attention(
-            q[:, 0], cache["k"], cache["v"], kv_len,
-            plan=plan, use_ctx_metadata=use_ctx_md, impl="pallas")
     else:
         out, ck, cv = ops.decode_attention_update(
             q[:, 0], cache["k"], cache["v"], k_new[:, 0], v_new[:, 0],
-            write_t, kv_len, plan=plan, use_ctx_metadata=use_ctx_md)
+            write_t, kv_len, plan=plan, use_ctx_metadata=use_ctx_md,
+            impl=impl or cfg.attention_impl)
         cache = {"k": ck, "v": cv}
     y = jnp.einsum("bhk,hkd->bd", out, params["wo"])
     return y[:, None], cache

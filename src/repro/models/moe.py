@@ -175,7 +175,6 @@ def _moe_ep_shard_map(params: Params, cfg: ModelConfig, x: jax.Array,
     time on qwen3-moe train_4k); here the wire carries exactly
     2 x (E, C_local, d) per layer plus the output all-gather.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     moe = cfg.moe
@@ -256,7 +255,7 @@ def _moe_ep_shard_map(params: Params, cfg: ModelConfig, x: jax.Array,
         return out.reshape(Bl, Ll, d), aux
 
     dspec = data_axes if data_axes else None
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, axis, None),
                   P(None, None),        # router: replicated (routing is global)
@@ -264,7 +263,7 @@ def _moe_ep_shard_map(params: Params, cfg: ModelConfig, x: jax.Array,
                   P(axis, dspec, None),
                   P(axis, None, dspec)),
         out_specs=(P(bspec, axis, None), P()),
-        check_rep=False)
+        check_vma=False)
     return fn(x, router, wi_g, wi_u, wo)
 
 

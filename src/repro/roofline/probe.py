@@ -29,7 +29,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
-from repro import compat
 from repro.configs.base import (
     ModelConfig,
     OptimizerConfig,
@@ -95,7 +94,7 @@ def _measure(arch_cfg: ModelConfig, shape: ShapeConfig, mesh, policy: str
         scfg = ServeConfig(model=arch_cfg, shape=shape, split_policy=policy)
         bundle = build_mesh_decode_step(model, scfg, mesh)
     compiled = bundle.step.lower(*bundle.abstract_args()).compile()
-    cost = compat.cost_analysis(compiled)
+    cost = dict(compiled.cost_analysis() or {})
     coll = collective_bytes(compiled.as_text())
     return ProbeCost(float(cost.get("flops", 0.0)),
                      float(cost.get("bytes accessed", 0.0)),

@@ -72,7 +72,6 @@ def build_compressed_dp_grads(loss_fn: Callable, mesh, *,
     DP-only layout: params replicated, batch sharded on ``data_axis``;
     gradients cross the wire as int8.  Composable with the AdamW update.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def per_replica(params, batch, ef):
@@ -85,12 +84,12 @@ def build_compressed_dp_grads(loss_fn: Callable, mesh, *,
     pspec = jax.tree.map(lambda _: P(), {"_": 0})["_"]
 
     def grad_fn(params, batch, ef):
-        f = shard_map(
+        f = jax.shard_map(
             per_replica, mesh=mesh,
             in_specs=(P(), jax.tree.map(lambda _: P(data_axis), batch),
                       P()),
             out_specs=(P(), P(), P()),
-            check_rep=False)
+            check_vma=False)
         return f(params, batch, ef)
 
     return grad_fn

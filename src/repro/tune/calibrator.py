@@ -48,7 +48,7 @@ class Calibrator:
     """Resolve ``spec`` into a :class:`SplitTable` (measure -> decide)."""
 
     def __init__(self, spec: TuneSpec, *, mode: str = "auto",
-                 seed: int = 0, interpret: bool = True):
+                 seed: int = 0):
         if mode not in MODES:
             raise ValueError(f"unknown mode {mode!r}; known: {MODES}")
         if mode == "auto":
@@ -57,7 +57,6 @@ class Calibrator:
         self.spec = spec
         self.mode = mode
         self.seed = seed
-        self.interpret = interpret
 
     # --- timing -------------------------------------------------------------
 
@@ -95,13 +94,14 @@ class Calibrator:
         ``ops.decode_attention`` dispatch, with the cell's scale leaves
         passed through — ``impl="pallas"`` times the fused in-register-
         dequant kernel, ``impl="xla"`` times the dequant-then-attend
-        reference (each under its own table family).
+        reference (each under its own table family).  Pallas launches
+        are Mosaic-compiled on an accelerator; only the CPU backend runs
+        them in the interpreter (:func:`repro.kernels.resolve_interpret`).
         """
         from repro.kernels import ops   # local: keep import cost off the
         #                                 modeled-only (CI) path
         plan = Planner(num_splits_override=s, impl=impl).plan(
             AttentionSpec.from_workload(w))
-        interpret = self.interpret
         args = self._inputs(w, cell)
 
         if len(args) == 6:              # quantized cell (fused harness)
@@ -109,12 +109,12 @@ class Calibrator:
             def step(q, k, v, k_s, v_s, kv_len):
                 return ops.decode_attention(
                     q, k, v, kv_len, k_scale=k_s, v_scale=v_s,
-                    plan=plan, impl=impl, interpret=interpret)
+                    plan=plan, impl=impl)
         else:
             @jax.jit
             def step(q, k, v, kv_len):
                 return ops.decode_attention(q, k, v, kv_len, plan=plan,
-                                            impl=impl, interpret=interpret)
+                                            impl=impl)
 
         for _ in range(max(1, self.spec.warmup)):   # compile + warmup
             step(*args).block_until_ready()

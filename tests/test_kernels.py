@@ -1,4 +1,5 @@
-"""Pallas kernels vs the pure-jnp oracles (interpret=True on CPU).
+"""Pallas kernels vs the pure-jnp oracles, run by the Pallas interpreter
+(the CPU backend's ``resolve_interpret`` default).
 
 Shape/dtype sweeps per the assignment: every kernel asserts allclose
 against ref.py on a grid of (batch, heads, lengths, dims, splits).
@@ -9,13 +10,32 @@ import numpy as np
 import pytest
 from _hyp_compat import given, settings, strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, resolve_interpret
 from repro.kernels.flash_decode import flash_decode_partials
 from repro.kernels.flash_prefill import flash_prefill
 
 
 def _rand(rng, shape, dtype=jnp.float32, scale=1.0):
     return (jax.random.normal(rng, shape, jnp.float32) * scale).astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# interpret-mode resolution: the interpreter only ever runs on the CPU
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False)])
+def test_resolve_interpret_follows_backend(monkeypatch, backend, interpret):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_interpret() is interpret
+    assert resolve_interpret(None) is interpret
+    assert resolve_interpret(False) is False     # AOT compiles for a TPU
+
+
+def test_resolve_interpret_refuses_interpreter_on_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="emulate"):
+        resolve_interpret(True)
 
 
 # ---------------------------------------------------------------------------
@@ -86,7 +106,7 @@ def test_flash_decode_kernel_vs_oracle(b, hkv, g, lk, s, dtype):
     kv_len = jax.random.randint(ks[3], (b,), 1, lk + 1)
 
     got = ops.decode_attention(
-        q, k, v, kv_len, impl="pallas", interpret=True,
+        q, k, v, kv_len, impl="pallas",
         metadata=__import__("repro.core.scheduler_metadata",
                             fromlist=["get_scheduler_metadata"]
                             ).get_scheduler_metadata(
@@ -154,7 +174,7 @@ def test_flash_prefill_vs_oracle(b, hq, hkv, lq, lk, window, offset, dtype):
     k = _rand(ks[1], (b, lk, hkv, d), dtype)
     v = _rand(ks[2], (b, lk, hkv, d), dtype)
     got = flash_prefill(q, k, v, causal=True, window=window,
-                        q_offset=offset, interpret=True)
+                        q_offset=offset)
     want = ref.naive_attention(q, k, v, causal=True, window=window,
                                q_offset=offset)
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
