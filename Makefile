@@ -9,10 +9,10 @@ PY := PYTHONPATH=src python
 
 .PHONY: verify verify-all bench golden plan-golden tune-golden \
 	serving-smoke cache-smoke prefix-smoke tune-smoke spec-smoke \
-	quant-smoke shard-smoke obs-smoke
+	quant-smoke shard-smoke
 
 verify: plan-golden tune-golden serving-smoke cache-smoke prefix-smoke \
-	tune-smoke spec-smoke quant-smoke shard-smoke obs-smoke
+	tune-smoke spec-smoke quant-smoke shard-smoke
 	$(PY) -m pytest -q -m "not multidevice and not slow"
 
 # seconds-scale serving A/B: fused-prefill admission must stay O(1)
@@ -53,13 +53,6 @@ quant-smoke:
 # (re-execs itself under 8 forced host devices)
 shard-smoke:
 	$(PY) -m benchmarks.shard_ab --smoke
-
-# seconds-scale observability A/B: tracing on/off must leave greedy
-# tokens + PlanCacheStats bit-identical and traced policy evals at 0,
-# while the on-cell dumps a schema-valid Chrome trace (request spans
-# over provenance-stamped launch spans) + metrics snapshot (structural)
-obs-smoke:
-	$(PY) -m benchmarks.obs_ab --smoke
 
 # seconds-scale tuning A/B: measured policy never slower than the
 # analytic policies on covered shapes, counted paper fallback elsewhere,

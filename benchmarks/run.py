@@ -13,7 +13,6 @@ def main() -> None:
         cache_ab,
         mesh_split_ab,
         metadata_ab,
-        obs_ab,
         prefix_ab,
         quant_ab,
         regression_sweep,
@@ -47,8 +46,6 @@ def main() -> None:
          quant_ab.main),
         ("shard_ab (single vs dp slot shards vs sp seq-sharded decode; "
          "re-execs under 8 forced devices)", shard_ab.main),
-        ("obs_ab (tracing on vs off: bit-identical serving + "
-         "Perfetto-loadable timeline)", obs_ab.main),
         ("mesh_split_ab smoke (pod policy A/B; re-execs under 16 "
          "forced devices — full 512-device run stays manual)",
          mesh_split_ab.smoke_main),

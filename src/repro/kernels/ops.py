@@ -27,6 +27,7 @@ push a ``LaunchPlan``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 from typing import Callable, NamedTuple, Optional
 
@@ -41,6 +42,7 @@ from repro.kernels.flash_decode import (DEFAULT_BLOCK_K,
                                         flash_decode_quant_partials)
 from repro.kernels.flash_prefill import flash_prefill
 from repro.plan import LaunchPlan, current_plan, plan_scope
+from repro.sharding.ctx import current_mesh
 
 _DEFAULT_POLICY = "paper"
 
@@ -264,8 +266,17 @@ def attention(
     if impl == "pallas":
         if not isinstance(q_offset, int):
             raise ValueError("pallas prefill path needs a static q_offset")
-        return flash_prefill(q, k, v, causal=causal, window=window,
-                             q_offset=q_offset, interpret=interpret)
+        kernel = functools.partial(flash_prefill, causal=causal,
+                                   window=window, q_offset=q_offset,
+                                   interpret=interpret)
+        mesh = current_mesh()
+        if mesh is not None and mesh.size > 1:
+            # GSPMD cannot partition a Mosaic kernel: every chip runs it
+            # on the whole prompt, as one chip would
+            from jax.sharding import PartitionSpec as P
+            kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(P(),) * 3,
+                                   out_specs=P(), check_vma=False)
+        return kernel(q, k, v)
     if impl == "naive":
         return ref.naive_attention(q, k, v, causal=causal, window=window,
                                    q_offset=q_offset)

@@ -8,10 +8,11 @@ cache / tune / spec / quant / shard):
   constructor (returns :data:`NULL_OBSERVER` when disabled).
 - :class:`Observer`        — the resolver output the serving engines
   call into: per-request lifecycle hooks (submit -> queue-wait ->
-  admit -> first token -> per-step decode/verify -> finish), per-launch
-  spans stamped with LaunchPlan provenance, structured warnings, and
-  occupancy gauges.  :meth:`Observer.shard_view` merges per-shard
-  labels onto one clock.
+  admit -> first token -> finish), per-launch spans stamped with
+  LaunchPlan provenance, ``engine.*`` spans that also reach the
+  profiler (:meth:`Observer.span`), structured warnings, and occupancy
+  gauges.  :meth:`Observer.shard_view` merges per-shard labels onto one
+  clock.
 - :class:`Tracer` / :class:`TraceArtifact` — Chrome trace-event JSON
   (Perfetto-loadable), schema-gated by :func:`validate_trace`.
 - :class:`MetricsRegistry` — counters / gauges / fixed-bucket
@@ -19,8 +20,8 @@ cache / tune / spec / quant / shard):
   the snapshot's ``plan_cache`` section absorbs ``PlanCacheStats``
   (``to_json`` shape preserved).
 
-Everything here is strictly host-side: nothing is traced, jitted, or
-placed on device, and the disabled path allocates nothing per step.
+Everything here is strictly host-side: nothing is jitted or placed on
+device, and the disabled path allocates nothing per step.
 """
 from repro.obs.config import ObsConfig, resolve_obs  # noqa: F401
 from repro.obs.io import (  # noqa: F401

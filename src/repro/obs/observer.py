@@ -12,9 +12,19 @@ finish sites.
 Strictly host-side and zero-cost when disabled: the engine guards every
 call with ``if self._obs.enabled:``, and the disabled singleton is
 :data:`NULL_OBSERVER` (``enabled = False``, every hook a no-op) — no
-per-step allocation, nothing inside jitted code, ``policy_eval_count``
+per-step allocation, nothing in the compiled programs (the
+``engine.trace.*`` spans run only while JAX traces a program's Python
+body), ``policy_eval_count``
 stays 0 and greedy streams stay bit-identical with tracing on
 (property-tested in ``tests/test_obs.py``).
+
+Engine spans (:meth:`Observer.span`) go to two sinks from one enter and
+one exit: a ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation``
+for ``engine.step``), which puts the span on the profiler's clock in
+the ``.xplane.pb`` host plane beside the device's programs, and a
+Chrome ``X`` event on the Observer's clock on the pid's track 0.
+Launch spans reuse the stamps of the dispatch and sync spans that
+bracket the launch, so there is one clock path.
 
 Sharded topologies call :meth:`Observer.shard_view` once per dp shard:
 views share the tracer, registry and clock, bind ``pid = shard`` on
@@ -26,7 +36,9 @@ PlanCacheStats ride the snapshot's ``plan_cache`` section through the
 from __future__ import annotations
 
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.obs.io import atomic_write_json, atomic_write_text
 from repro.obs.metrics import MetricsRegistry
@@ -76,6 +88,45 @@ class _Rec:
         self.prompt_len = prompt_len
         self.kind: Optional[str] = None
         self.ntokens = 0
+
+
+class _Span:
+    """One engine span: a profiler annotation and a Chrome ``X`` event
+    stamped inside it (``t0`` / ``t1``, microseconds on the Observer's
+    clock, readable after exit)."""
+    __slots__ = ("_obs", "_name", "_args", "_ann", "t0", "t1")
+
+    def __init__(self, obs: "Observer", name: str, ann: Any,
+                 args: Dict[str, int]) -> None:
+        self._obs, self._name, self._ann, self._args = obs, name, ann, args
+        self.t0 = self.t1 = 0
+
+    def __enter__(self) -> "_Span":
+        self._ann.__enter__()
+        self.t0 = self._obs.now_us()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        obs = self._obs
+        self.t1 = obs.now_us()
+        self._ann.__exit__(*exc)
+        if obs.tracer is not None:
+            obs.tracer.complete(obs.pid, 0, self._name, "engine", self.t0,
+                                self.t1 - self.t0, self._args or None)
+
+
+class _NoSpan:
+    """What an engine enters in place of a span when not observing."""
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        return None
+
+
+NO_SPAN = _NoSpan()
 
 
 class NullObserver:
@@ -141,7 +192,7 @@ class Observer:
             # under the generic engine name (force=True)
             tracer.ensure_process(pid, process_name,
                                   force=parent is not None)
-            tracer.ensure_thread(pid, 0, "launches")
+            tracer.ensure_thread(pid, 0, "engine")
         if metrics is not None:
             self._bind_metrics(metrics)
 
@@ -177,6 +228,10 @@ class Observer:
             "launch_ms", "wall-clock per launch by kind, ms")
         self._m_launches = m.counter(
             "launches_total", "launches by kind")
+        self._m_traces = m.counter(
+            "program_traces_total",
+            "times JAX traced an engine program, by kind (a retrace "
+            "counts even where the compile cache hides the compile)")
         self._m_slots_live = m.gauge(
             "slots_live", "occupied decode slots (last step)").labels(**lb)
         self._m_slots_total = m.gauge(
@@ -253,19 +308,36 @@ class Observer:
             self._m_prefix_rows.inc(int(shared_rows))
             self._m_prefix_bytes.inc(int(shared_bytes))
 
+    def span(self, name: str, *, step_num: Optional[int] = None,
+             **args: int) -> _Span:
+        """A context manager that records ``name`` in both sinks: the
+        profiler (``StepTraceAnnotation`` when ``step_num`` is given)
+        and a Chrome ``X`` event on this pid's engine track.  ``args``
+        are a few ints (request id, prompt length, bucket, live
+        slots)."""
+        if step_num is not None:
+            ann = StepTraceAnnotation(name, step_num=step_num, **args)
+            args = dict(args, step=step_num)
+        else:
+            ann = TraceAnnotation(name, **args)
+        return _Span(self, name, ann, args)
+
+    def program_trace(self, kind: str) -> _Span:
+        """Count one trace of a ``kind`` engine program and return its
+        ``engine.trace.<kind>`` span.  Entered from the program's Python
+        body, which runs only while JAX traces it."""
+        if self.metrics is not None:
+            self._m_traces.inc(1, kind=kind, **self.labels)
+        return self.span(f"engine.trace.{kind}")
+
     def on_launch(self, kind: str, key: Any, plan: Any, t0: int,
-                  handles: Sequence[int] = ()) -> None:
-        """Close one launch span ``[t0, now)`` on the pid's "launches"
-        track, stamped with the plan's provenance; ``handles`` mirror
-        the span onto each rider's request track (decode/verify rows)."""
-        t1 = self.now_us()
+                  t1: int) -> None:
+        """Record one launch ``[t0, t1)`` on the pid's engine track,
+        stamped with the plan's provenance: ``t0`` opens the dispatch
+        span and ``t1`` closes the host sync span of that launch."""
         if self.tracer is not None:
             self.tracer.complete(self.pid, 0, kind, "launch", t0, t1 - t0,
                                  plan_provenance(key, plan))
-            for h in handles:
-                if h in self._recs:
-                    self.tracer.complete(self.pid, h + 1, kind, "step",
-                                         t0, t1 - t0)
         if self.metrics is not None:
             self._m_launches.inc(1, kind=kind, **self.labels)
             self._m_launch.observe((t1 - t0) / 1e3, kind=kind,

@@ -12,13 +12,13 @@ microsecond ints.  Events use the Chrome trace-event JSON format
 
 Track layout: ``pid`` is the shard index (process_name ``shard{d}``
 under a sharded topology, the engine name otherwise); ``tid 0`` is the
-"launches" track carrying per-launch spans stamped with LaunchPlan
-provenance; ``tid = handle + 1`` is one track per request carrying its
-lifecycle spans (queue_wait / admit / per-step decode/verify rows under
-the enclosing "request" span).
+"engine" track carrying the ``engine.*`` spans and the per-launch spans
+stamped with LaunchPlan provenance; ``tid = handle + 1`` is one track
+per request carrying its lifecycle spans (queue_wait / admit under the
+enclosing "request" span).
 
-:func:`validate_trace` is the schema gate the obs smoke and the trace
-tests assert through: key/type checks per event plus per-track nesting
+:func:`validate_trace` is the schema gate the trace tests assert
+through: key/type checks per event plus per-track nesting
 consistency — on any one (pid, tid) track, X spans must form a proper
 forest (contained or disjoint, never partially overlapping) with
 non-negative durations.

@@ -114,6 +114,7 @@ from repro.configs.base import ServeConfig
 from repro.core.split_policy import KV_DTYPES, get_policy
 from repro.models.registry import Model
 from repro.obs import atomic_write_json, resolve_obs
+from repro.obs.observer import NO_SPAN
 from repro.plan import LaunchPlan, PlanCacheStats, Planner, plan_scope
 from repro.serving.events import (
     FINISH_CACHE_CAPACITY,
@@ -143,6 +144,16 @@ PREFILL_MODES = ("auto", "fused", "loop")
 
 
 PARAM_POLICIES = ("replicated", "tp")
+
+
+def _named_jit(impl, name: str, **static: Any):
+    """``jax.jit`` of ``impl`` with ``static`` bound, donating the cache
+    (argument 1), under a stable program name: the XLA module reads
+    ``jit_<name>`` where an anonymous partial would read
+    ``jit__unknown``."""
+    fn = functools.partial(impl, **static)
+    fn.__name__ = name
+    return jax.jit(fn, donate_argnums=(1,))
 
 
 class ServingEngine:
@@ -388,6 +399,7 @@ class ServingEngine:
         self._spec_rejects = [0] * self.B
 
         self._next_handle = 0
+        self._steps = 0                 # step() calls (engine.step spans)
         self._queues: Dict[int, Deque[Event]] = {}
         self._completions: Dict[int, Completion] = {}
         self._undrained: List[int] = []
@@ -447,6 +459,18 @@ class ServingEngine:
         """Compute (not cache) the decode launch plan for ``t_max``."""
         return self.sched.decode_plan(t_max)
 
+    def _span(self, name: str, **args: int) -> Any:
+        """An ``engine.*`` span in the profiler and the observer's trace
+        while observing; a shared no-op otherwise."""
+        return self._obs.span(name, **args) if self._obs.enabled \
+            else NO_SPAN
+
+    def _traced(self, kind: str) -> Any:
+        """Entered by a program's Python body, which runs only while JAX
+        traces it: counts the trace and spans it while observing."""
+        return self._obs.program_trace(kind) if self._obs.enabled \
+            else NO_SPAN
+
     # --- state --------------------------------------------------------------
 
     def load(self, params: Pytree) -> None:
@@ -458,9 +482,8 @@ class ServingEngine:
         # zeroed storage would leak phantom allocations)
         self.cache = self.model.cache_manager(self.B, self.max_len,
                                               **self._cache_kw)
-        self._caches = self.cache.init_storage()
-        if self.mesh is not None:
-            self._caches = self._place_caches(self._caches)
+        self._caches = self.cache.init_storage() if self.mesh is None \
+            else self._placed_storage()
         self._state = self.sampler.init_state(self.B)
         self._state_dev = None
 
@@ -475,37 +498,44 @@ class ServingEngine:
             return jax.device_put(params, sh)
         return jax.device_put(params, replicated(self.mesh))
 
-    def _place_caches(self, storage: Pytree) -> Pytree:
-        """Land cache storage on the shard sub-mesh.  Dense storage
+    def _placed_storage(self) -> Pytree:
+        """Cache storage made directly on the shard sub-mesh, so that no
+        device ever holds more than its own share.  Dense storage
         sequence-shards its L dim over the model axis when the fused
         path is on; the paged page pool stays replicated (the gathered
         view is re-partitioned per launch by the shard_map)."""
+        init = self.cache.init_storage
         if self.seq_shards > 1 and not self.cache.is_paged:
             axes = self.model.cache_axes(self.B, self.max_len,
                                          self.kv_dtype)
-            sh = tree_shardings(self.mesh, storage, axes,
+            sh = tree_shardings(self.mesh, jax.eval_shape(init), axes,
                                 cache_rules(True))
-            return jax.device_put(storage, sh)
-        return jax.device_put(storage, replicated(self.mesh))
+        else:
+            sh = replicated(self.mesh)
+        return jax.jit(init, out_shardings=sh)()
 
     # --- jitted impls -------------------------------------------------------
 
     def _decode_impl(self, params, caches, token, t, state,
                      plan: Optional[LaunchPlan] = None):
-        with activation_mesh(self.mesh):
-            logits, caches = self.model.decode_step(
-                params, caches, token, t, plan=plan, policy=self.policy)
-        tok = self.sampler.sample(logits, state, t)
+        with self._traced("decode"):
+            with activation_mesh(self.mesh):
+                logits, caches = self.model.decode_step(
+                    params, caches, token, t, plan=plan,
+                    policy=self.policy)
+            tok = self.sampler.sample(logits, state, t)
         return tok, caches
 
     def _prefill_impl(self, params, caches, tokens, slot, length, state,
                       plan: Optional[LaunchPlan] = None):
         """Fused single-slot prompt prefill + first-token sampling."""
-        with plan_scope(plan), activation_mesh(self.mesh):
-            logits, caches = self.model.prefill_slot(
-                params, caches, tokens, slot, length, self.max_len,
-                plan=plan, kv_dtype=self.kv_dtype)
-        tok = self.sampler.sample(logits[None], state, (length - 1)[None])
+        with self._traced("prefill"):
+            with plan_scope(plan), activation_mesh(self.mesh):
+                logits, caches = self.model.prefill_slot(
+                    params, caches, tokens, slot, length, self.max_len,
+                    plan=plan, kv_dtype=self.kv_dtype)
+            tok = self.sampler.sample(logits[None], state,
+                                      (length - 1)[None])
         return tok[0], caches
 
     def _zero_impl(self, caches, slot):
@@ -537,12 +567,13 @@ class ServingEngine:
         residency, not the padded slot capacity.
         """
         lay = self.cache.layout
-        view = lay.gather_view(storage, table, num_pages)
-        with activation_mesh(self.mesh):
-            logits, view = self.model.decode_step(
-                params, view, token, t, plan=plan, policy=self.policy)
-        tok = self.sampler.sample(logits, state, t)
-        storage = lay.write_token(storage, view, table, t, num_pages)
+        with self._traced("decode"):
+            view = lay.gather_view(storage, table, num_pages)
+            with activation_mesh(self.mesh):
+                logits, view = self.model.decode_step(
+                    params, view, token, t, plan=plan, policy=self.policy)
+            tok = self.sampler.sample(logits, state, t)
+            storage = lay.write_token(storage, view, table, t, num_pages)
         return tok, storage
 
     def _prefill_paged_impl(self, params, storage, tokens, slot, length,
@@ -551,13 +582,15 @@ class ServingEngine:
                             num_pages: int = 1):
         """Fused single-slot prefill straight into the slot's pages."""
         lay = self.cache.layout
-        with plan_scope(plan), activation_mesh(self.mesh):
-            logits, view = self.model.prefill_slot_view(
-                params, storage, tokens, slot, length,
-                num_pages * self.cache.spec.page_size,
-                plan=plan, kv_dtype=self.kv_dtype)
-        tok = self.sampler.sample(logits[None], state, (length - 1)[None])
-        storage = lay.write_slot(storage, view, table, slot, num_pages)
+        with self._traced("prefill"):
+            with plan_scope(plan), activation_mesh(self.mesh):
+                logits, view = self.model.prefill_slot_view(
+                    params, storage, tokens, slot, length,
+                    num_pages * self.cache.spec.page_size,
+                    plan=plan, kv_dtype=self.kv_dtype)
+            tok = self.sampler.sample(logits[None], state,
+                                      (length - 1)[None])
+            storage = lay.write_slot(storage, view, table, slot, num_pages)
         return tok[0], storage
 
     def _verify_impl(self, params, caches, tokens, t, dlen, state,
@@ -566,11 +599,13 @@ class ServingEngine:
         token rows in one planned launch, accept/reject in-batch.
         ``dlen`` (B,) is each slot's TRUE draft count — ``accepted`` is
         clamped by it so mixed-k padding rows never commit."""
-        with activation_mesh(self.mesh):
-            logits, caches = self.model.verify_step(
-                params, caches, tokens, t, plan=plan)
-        toks, acc = self.sampler.verify(logits, tokens[:, 1:], state, t)
-        acc = jnp.minimum(acc, dlen)
+        with self._traced("verify"):
+            with activation_mesh(self.mesh):
+                logits, caches = self.model.verify_step(
+                    params, caches, tokens, t, plan=plan)
+            toks, acc = self.sampler.verify(logits, tokens[:, 1:], state,
+                                            t)
+            acc = jnp.minimum(acc, dlen)
         return toks, acc, caches
 
     def _verify_paged_impl(self, params, storage, tokens, t, dlen, state,
@@ -582,14 +617,16 @@ class ServingEngine:
         never reach storage (their span pages are redirected to the
         trash page inside the jitted step)."""
         lay = self.cache.layout
-        view = lay.gather_view(storage, table, num_pages)
-        with activation_mesh(self.mesh):
-            logits, view = self.model.verify_step(
-                params, view, tokens, t, plan=plan)
-        toks, acc = self.sampler.verify(logits, tokens[:, 1:], state, t)
-        acc = jnp.minimum(acc, dlen)
-        storage = lay.write_rows(storage, view, table, t, acc + 1,
-                                 tokens.shape[1], num_pages)
+        with self._traced("verify"):
+            view = lay.gather_view(storage, table, num_pages)
+            with activation_mesh(self.mesh):
+                logits, view = self.model.verify_step(
+                    params, view, tokens, t, plan=plan)
+            toks, acc = self.sampler.verify(logits, tokens[:, 1:], state,
+                                            t)
+            acc = jnp.minimum(acc, dlen)
+            storage = lay.write_rows(storage, view, table, t, acc + 1,
+                                     tokens.shape[1], num_pages)
         return toks, acc, storage
 
     def _copy_page_impl(self, storage, src, dst):
@@ -613,53 +650,49 @@ class ServingEngine:
         view — rows [0, start) already resident from adopted pages —
         compute only the unshared suffix against it, scatter back."""
         lay = self.cache.layout
-        view = lay.slot_view(storage, table, slot, num_pages)
-        with plan_scope(plan), activation_mesh(self.mesh):
-            logits, view = self.model.prefill_suffix_view(
-                params, view, tokens, start, length,
-                plan=plan, kv_dtype=self.kv_dtype)
-        tok = self.sampler.sample(logits[None], state, (length - 1)[None])
-        storage = lay.write_slot(storage, view, table, slot, num_pages)
+        with self._traced("sprefill"):
+            view = lay.slot_view(storage, table, slot, num_pages)
+            with plan_scope(plan), activation_mesh(self.mesh):
+                logits, view = self.model.prefill_suffix_view(
+                    params, view, tokens, start, length,
+                    plan=plan, kv_dtype=self.kv_dtype)
+            tok = self.sampler.sample(logits[None], state,
+                                      (length - 1)[None])
+            storage = lay.write_slot(storage, view, table, slot, num_pages)
         return tok[0], storage
 
     def _build_decode(self, plan: LaunchPlan):
+        name = f"decode_b{plan.bucket}_s{plan.num_splits}"
         if self.cache.is_paged:
-            return jax.jit(
-                functools.partial(self._decode_paged_impl, plan=plan,
-                                  num_pages=self.cache.spec.view_pages(
-                                      plan.bucket)),
-                donate_argnums=(1,))
-        return jax.jit(functools.partial(self._decode_impl, plan=plan),
-                       donate_argnums=(1,))
+            return _named_jit(self._decode_paged_impl, name, plan=plan,
+                              num_pages=self.cache.spec.view_pages(
+                                  plan.bucket))
+        return _named_jit(self._decode_impl, name, plan=plan)
 
     def _build_prefill(self, plan: LaunchPlan):
+        name = f"prefill_b{plan.bucket}"
         if self.cache.is_paged:
-            return jax.jit(
-                functools.partial(self._prefill_paged_impl, plan=plan,
-                                  num_pages=self.cache.spec.view_pages(
-                                      plan.bucket)),
-                donate_argnums=(1,))
-        return jax.jit(functools.partial(self._prefill_impl, plan=plan),
-                       donate_argnums=(1,))
+            return _named_jit(self._prefill_paged_impl, name, plan=plan,
+                              num_pages=self.cache.spec.view_pages(
+                                  plan.bucket))
+        return _named_jit(self._prefill_impl, name, plan=plan)
 
     def _build_verify(self, plan: LaunchPlan):
+        name = (f"verify_k{plan.spec.seqlen_q - 1}_b{plan.bucket}"
+                f"_s{plan.num_splits}")
         if self.cache.is_paged:
-            return jax.jit(
-                functools.partial(self._verify_paged_impl, plan=plan,
-                                  num_pages=self.cache.spec.view_pages(
-                                      plan.bucket)),
-                donate_argnums=(1,))
-        return jax.jit(functools.partial(self._verify_impl, plan=plan),
-                       donate_argnums=(1,))
+            return _named_jit(self._verify_paged_impl, name, plan=plan,
+                              num_pages=self.cache.spec.view_pages(
+                                  plan.bucket))
+        return _named_jit(self._verify_impl, name, plan=plan)
 
     def _build_suffix_prefill(self, plan: LaunchPlan):
         # plan.bucket is the VIEW bucket (whole resident prompt): the
         # gather must span prefix + suffix, like decode's resident view
-        return jax.jit(
-            functools.partial(self._suffix_prefill_paged_impl, plan=plan,
-                              num_pages=self.cache.spec.view_pages(
-                                  plan.bucket)),
-            donate_argnums=(1,))
+        return _named_jit(
+            self._suffix_prefill_paged_impl,
+            f"sprefill_b{plan.bucket}_q{plan.spec.seqlen_q}", plan=plan,
+            num_pages=self.cache.spec.view_pages(plan.bucket))
 
     # --- request lifecycle --------------------------------------------------
 
@@ -728,21 +761,23 @@ class ServingEngine:
         decode launch over the live slots.  Returns the events."""
         assert self._params is not None, "call load(params) first"
         events: List[Event] = []
-        while True:
-            adm = self.sched.admit_next(self._admissible)
-            if adm is None:
-                break
-            self._admit(*adm, events)
-        live = self.sched.live()
-        if live:
-            self._decode_launch(live, events)
-        if self._obs.enabled:
-            occ, slots = self.sched.occupancy()
-            kw = (dict(free_pages=self.cache.free_pages,
-                       total_pages=self.cache.spec.total_pages)
-                  if self.cache.is_paged else {})
-            self._obs.sample_occupancy(occ, slots,
-                                       self.sched.queue_depth(), **kw)
+        self._steps += 1
+        with self._span("engine.step", step_num=self._steps):
+            while True:
+                adm = self.sched.admit_next(self._admissible)
+                if adm is None:
+                    break
+                self._admit(*adm, events)
+            live = self.sched.live()
+            if live:
+                self._decode_launch(live, events)
+            if self._obs.enabled:
+                occ, slots = self.sched.occupancy()
+                kw = (dict(free_pages=self.cache.free_pages,
+                           total_pages=self.cache.spec.total_pages)
+                      if self.cache.is_paged else {})
+                self._obs.sample_occupancy(occ, slots,
+                                           self.sched.queue_depth(), **kw)
         return events
 
     def _admissible(self, st: SlotState) -> bool:
@@ -835,6 +870,12 @@ class ServingEngine:
     # --- internals ----------------------------------------------------------
 
     def _admit(self, i: int, st: SlotState, events: List[Event]) -> None:
+        with self._span("engine.admit", request_id=st.request.request_id,
+                        prompt_len=len(st.request.prompt)):
+            self._admit_body(i, st, events)
+
+    def _admit_body(self, i: int, st: SlotState,
+                    events: List[Event]) -> None:
         if self._obs.enabled:           # closes the queue_wait span
             self._obs.on_admit_start(st.handle)
         # the whole prompt's pages are reserved up front (all-or-nothing;
@@ -907,12 +948,14 @@ class ServingEngine:
                     state_row)
             if self.cache.is_paged:
                 args += (self.cache.table_device(),)
-        t0 = self._obs.now_us() if self._obs.enabled else 0
-        tok, self._caches = entry.step(*args)
-        tok = int(tok)                  # device sync closes the launch
+        with self._span("engine.prefill.dispatch",
+                        bucket=entry.plan.bucket) as dispatch:
+            tok, self._caches = entry.step(*args)
+        with self._span("engine.prefill.sync") as sync:
+            tok = int(tok)              # device sync closes the launch
         if self._obs.enabled:
             self._obs.on_launch("sprefill" if shared else "prefill",
-                                entry.key, entry.plan, t0)
+                                entry.key, entry.plan, dispatch.t0, sync.t1)
         self.cache.note_write(i, n - 1)
         if self.share_prefix:
             # index this prompt's (now fully resident) full pages so the
@@ -937,6 +980,31 @@ class ServingEngine:
             self._plain_launch(live, events)
 
     def _plain_launch(self, live, events: List[Event]) -> None:
+        with self._span("engine.decode.prepare", live=len(live)):
+            prepared = self._prepare_decode(live, events)
+        if prepared is None:
+            return
+        live, entry, step, args = prepared
+        with self._span("engine.decode.dispatch",
+                        bucket=entry.key if entry is not None
+                        else self.max_len) as dispatch:
+            out, self._caches = step(*args)
+        with self._span("engine.decode.sync") as sync:
+            out = np.asarray(out)           # host sync closes the launch
+        if self._obs.enabled:
+            self._obs.on_launch(
+                "decode",
+                entry.key if entry is not None else None,
+                entry.plan if entry is not None else None,
+                dispatch.t0, sync.t1)
+        with self._span("engine.emit", live=len(live)):
+            for i, st in live:
+                self._advance(i, st, int(out[i]), events)
+
+    def _prepare_decode(self, live, events: List[Event]):
+        """Everything a decode launch needs before its dispatch: pages
+        and residency, the plan-cache lookup, the uploads.  Returns
+        ``(live, entry, step, args)``, or None when no slot is left."""
         if self.cache.is_paged:
             # every live slot is about to write row _pos[i]: allocate its
             # page now, and finish (only) the requests whose allocation
@@ -948,7 +1016,7 @@ class ServingEngine:
                     self._finish_capacity(i, st, events)
             live = self.sched.live()
             if not live:
-                return
+                return None
             if self.share_prefix:
                 # ensure() may have copy-on-written a shared page;
                 # its contents must land before this launch's gather
@@ -973,17 +1041,7 @@ class ServingEngine:
         args = (self._params, self._caches, tok, t, self._state_dev)
         if self.cache.is_paged:
             args += (self.cache.table_device(),)
-        t0 = self._obs.now_us() if self._obs.enabled else 0
-        out, self._caches = step(*args)
-        out = np.asarray(out)               # host sync closes the launch
-        if self._obs.enabled:
-            self._obs.on_launch(
-                "decode",
-                entry.key if entry is not None else None,
-                entry.plan if entry is not None else None, t0,
-                handles=[s.handle for _, s in live])
-        for i, st in live:
-            self._advance(i, st, int(out[i]), events)
+        return live, entry, step, args
 
     # --- speculative verify launch ------------------------------------------
 
@@ -1023,6 +1081,32 @@ class ServingEngine:
         """One planned verify launch: every live slot rides (lockstep),
         speculating slots carry their drafts, the rest take 1-token
         rows (``dlen = 0`` — behaviorally a decode row)."""
+        with self._span("engine.verify.prepare", live=len(live)):
+            prepared = self._prepare_verify(live, drafts, events)
+        if prepared is None:
+            return
+        live, entry, args = prepared
+        if entry is None:               # every draft culled: plain step
+            self._plain_launch(live, events)
+            return
+        with self._span("engine.verify.dispatch",
+                        bucket=entry.plan.bucket) as dispatch:
+            out, acc, self._caches = entry.step(*args)
+        with self._span("engine.verify.sync") as sync:
+            out, acc = np.asarray(out), np.asarray(acc)
+        if self._obs.enabled:
+            self._obs.on_launch("verify", entry.key, entry.plan,
+                                dispatch.t0, sync.t1)
+        with self._span("engine.emit", live=len(live)):
+            for i, st in live:
+                self._advance_verified(i, st, drafts.get(i, []),
+                                       int(acc[i]), out[i], events)
+
+    def _prepare_verify(self, live, drafts: Dict[int, List[int]],
+                        events: List[Event]):
+        """Pages, draft rows and uploads of a verify launch.  Returns
+        ``(live, entry, args)``; ``entry`` is None when every draft was
+        culled, and the whole is None when no slot is left."""
         if self.cache.is_paged:
             # each slot writes rows [pos, pos + dlen]: allocate row pos
             # like decode (failure finishes the request), then extend
@@ -1044,13 +1128,12 @@ class ServingEngine:
                 drafts[i] = d[:kept]
             live = self.sched.live()
             if not live:
-                return
+                return None
             if self.share_prefix:
                 self._apply_copies()
         K = max((len(drafts.get(i, [])) for i, _ in live), default=0)
-        if K == 0:                      # every draft culled: plain step
-            self._plain_launch(live, events)
-            return
+        if K == 0:
+            return live, None, None
         toks = np.zeros((self.B, K + 1), np.int32)
         dlen = np.zeros(self.B, np.int32)
         toks[:, 0] = self._next_token
@@ -1070,15 +1153,7 @@ class ServingEngine:
                 self._state_dev)
         if self.cache.is_paged:
             args += (self.cache.table_device(),)
-        t0 = self._obs.now_us() if self._obs.enabled else 0
-        out, acc, self._caches = entry.step(*args)
-        out, acc = np.asarray(out), np.asarray(acc)
-        if self._obs.enabled:
-            self._obs.on_launch("verify", entry.key, entry.plan, t0,
-                                handles=[s.handle for _, s in live])
-        for i, st in live:
-            self._advance_verified(i, st, drafts.get(i, []),
-                                   int(acc[i]), out[i], events)
+        return live, entry, args
 
     def _advance_verified(self, i: int, st: SlotState, d: List[int],
                           a: int, row: np.ndarray,

@@ -1,10 +1,15 @@
 """repro.obs: metrics registry / tracer / validate_trace units, the
 Observer lifecycle on an injectable fake clock, atomic artifact writes,
-deterministic engine traces, the tracing-on/off bit-identity property
-(dense/paged x single/dp-sharded), warning-once regressions, and
-(multidevice tier) dp=2 artifact parity in an 8-device subprocess."""
+deterministic engine traces, the engine's spans (both sinks, nesting,
+nothing entered when disabled), stable program names, program trace
+counts, the tracing-on/off bit-identity property (dense/paged x
+single/dp-sharded), warning-once regressions, and (multidevice tier)
+dp=2 artifact parity in an 8-device subprocess and the span table of
+an sp=2 sequence-sharded engine."""
+import functools
 import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -29,11 +34,14 @@ from repro.obs import (
     plan_provenance,
     validate_trace,
 )
+from repro.obs import observer as observer_mod
 from repro.obs.metrics import _percentile
 from repro.plan import AttentionSpec, Planner
-from repro.serving import Request, ServingEngine
+from repro.serving import Request, SamplingParams, ServingEngine
+from repro.serving import engine as engine_mod
 from repro.shard import ShardSpec, ShardedServingEngine, \
     clear_shard_plan_caches
+from repro.spec import SpecConfig
 from repro.tune.table import REFERENCE_TABLE_PATH
 
 REPO = Path(__file__).resolve().parents[1]
@@ -273,7 +281,7 @@ def test_observer_lifecycle_spans_and_metrics():
     obs.on_submit(0, 7, 5)
     obs.on_admit_start(0)
     t0 = obs.now_us()
-    obs.on_launch("prefill", ("prefill", 128), None, t0, handles=(0,))
+    obs.on_launch("prefill", ("prefill", 128), None, t0, obs.now_us())
     obs.on_admit_end(0, "full")
     obs.on_token(0, 0)
     obs.on_token(0, 1)
@@ -282,16 +290,20 @@ def test_observer_lifecycle_spans_and_metrics():
     art.validate()
     req = art.spans("request")[0]
     qw, admit = art.spans("queue_wait")[0], art.spans("admit")[0]
-    # request encloses queue_wait, admit and the mirrored step span
-    for child in (qw, admit, art.spans("prefill", cat="step")[0]):
+    # request encloses queue_wait and admit; launches stay on the
+    # engine track alone (no per-rider copy on the request's track)
+    for child in (qw, admit):
         assert req["ts"] <= child["ts"]
         assert child["ts"] + child["dur"] <= req["ts"] + req["dur"]
+    assert not art.spans(cat="step")
+    assert [e["tid"] for e in art.spans(cat="launch")] == [0]
     assert req["args"] == {"request_id": 7, "prompt_len": 5,
                            "prefill": "full", "finish_reason": "length",
                            "tokens": 2}
     assert art.instants("first_token")
     launch = art.spans("prefill", cat="launch")[0]
     assert launch["tid"] == 0 and launch["args"]["key"] == "prefill/128"
+    assert launch["ts"] == t0 and launch["dur"] == 1000   # 1 ms a reading
     mx = obs.metrics_snapshot()["metrics"]
     assert mx["requests_submitted_total"]["aggregate"] == 1
     assert mx["requests_finished_total"]["series"] == {"reason=length": 1}
@@ -383,6 +395,243 @@ def test_engine_trace_is_deterministic_under_fake_clock(tiny_model):
     assert {"prefill", "decode"} <= {sp["name"]
                                      for sp in ea.spans(cat="launch")}
     assert a.metrics_snapshot() == b.metrics_snapshot()
+
+
+# ---------------------------------------------------------------------------
+# engine spans: one API, two sinks (profiler + Chrome trace)
+# ---------------------------------------------------------------------------
+
+
+class _CountingAnnotation:
+    """Stands in for a jax.profiler annotation and logs each entry."""
+
+    log: list = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, kw
+
+    def __enter__(self):
+        self.log.append((type(self).__name__, self.name, self.kw))
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Every profiler annotation the observer enters, in order."""
+    log = []
+
+    class Trace(_CountingAnnotation):
+        pass
+
+    class Step(_CountingAnnotation):
+        pass
+
+    Trace.log = Step.log = log
+    monkeypatch.setattr(observer_mod, "TraceAnnotation", Trace)
+    monkeypatch.setattr(observer_mod, "StepTraceAnnotation", Step)
+    return log
+
+
+def test_observer_span_feeds_profiler_and_trace(annotations):
+    obs = ObsConfig(trace=True, clock=FakeClock()).resolve()
+    with obs.span("engine.step", step_num=3):
+        with obs.span("engine.decode.dispatch", bucket=256) as d:
+            pass
+    assert annotations == [("Step", "engine.step", {"step_num": 3}),
+                           ("Trace", "engine.decode.dispatch",
+                            {"bucket": 256})]
+    art = obs.tracer.artifact()
+    art.validate()
+    disp, step = art.spans(cat="engine")
+    assert (disp["name"], disp["tid"], disp["args"]) == \
+        ("engine.decode.dispatch", 0, {"bucket": 256})
+    assert (disp["ts"], disp["ts"] + disp["dur"]) == (d.t0, d.t1)
+    assert step["args"] == {"step": 3}
+    assert step["ts"] < disp["ts"] and \
+        disp["ts"] + disp["dur"] < step["ts"] + step["dur"]
+
+
+def _covers(outer, inner):
+    return outer["ts"] <= inner["ts"] and \
+        inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+
+
+def _check_engine_spans(art, launches=("decode", "prefill")):
+    """The span table: every engine span nests where it belongs, and
+    each launch span runs from its dispatch's start to its sync's end."""
+    art.validate()
+    spans = art.spans(cat="engine")
+    by = {}
+    for e in spans:
+        by.setdefault(e["name"], []).append(e)
+    steps = by["engine.step"]
+    parents = {"engine.admit": ("engine.step",),
+               "engine.prefill.dispatch": ("engine.admit",),
+               "engine.prefill.sync": ("engine.admit",),
+               "engine.decode.prepare": ("engine.step",),
+               "engine.decode.dispatch": ("engine.step",),
+               "engine.decode.sync": ("engine.step",),
+               "engine.verify.prepare": ("engine.step",),
+               "engine.verify.dispatch": ("engine.step",),
+               "engine.verify.sync": ("engine.step",),
+               "engine.emit": ("engine.step",)}
+    for name, outer in parents.items():
+        for e in by.get(name, []):
+            assert any(_covers(o, e) for n in outer for o in by[n]), \
+                f"{name} at {e['ts']} lies under no {outer}"
+    for name, es in by.items():
+        if name.startswith("engine.trace."):
+            for e in es:
+                assert any(_covers(o, e) for n in by
+                           if n.endswith(".dispatch") for o in by[n]), \
+                    f"{name} traced outside a dispatch"
+    for e in art.spans(cat="launch"):
+        kind = "prefill" if e["name"] in ("prefill", "sprefill") \
+            else e["name"]
+        d = [x for x in by[f"engine.{kind}.dispatch"] if x["ts"] == e["ts"]]
+        s = [x for x in by[f"engine.{kind}.sync"]
+             if x["ts"] + x["dur"] == e["ts"] + e["dur"]]
+        assert d and s, f"launch {e['name']} at {e['ts']} off its stamps"
+    assert [e["args"]["step"] for e in steps] == \
+        list(range(1, len(steps) + 1))
+    for kind in launches:
+        assert f"engine.{kind}.dispatch" in by, sorted(by)
+    return by
+
+
+_REPEATS = [[5, 6, 7, 5, 6, 7, 5, 6], [1, 2, 3, 4, 1, 2, 3],
+            [9, 9, 8, 9, 9, 8, 9]]
+
+
+@pytest.mark.parametrize("case", ["dense", "paged", "paged_spec",
+                                  "sharded"])
+def test_engine_spans_nest_under_step(tiny_model, case):
+    cfg, model, params = tiny_model
+    obs = ObsConfig(trace=True, metrics=True, clock=FakeClock()).resolve()
+    layout = "dense" if case in ("dense", "sharded") else "paged"
+    scfg = ServeConfig(model=cfg, cache_layout=layout)
+    spec = SpecConfig(method="ngram", k=4) if case == "paged_spec" \
+        else None
+    reqs = [Request(i, p, max_new_tokens=16,
+                    sampling=SamplingParams(speculation=spec))
+            for i, p in enumerate(_REPEATS)]
+    eng, _ = _serve(model, scfg, reqs, obs=obs, max_len=96,
+                    sharded=case == "sharded")
+    art = obs.tracer.artifact()
+    by = _check_engine_spans(
+        art, ("prefill", "verify") if spec else ("prefill", "decode"))
+    assert len(by["engine.admit"]) == len(reqs)
+    assert {e["args"]["request_id"] for e in by["engine.admit"]} == \
+        {0, 1, 2}
+    assert {"engine.trace.prefill"} <= set(by)
+    if case == "sharded":
+        assert {e["pid"] for e in art.spans(cat="engine")} == {0}
+
+
+def test_disabled_observer_enters_no_trace_annotation(tiny_model,
+                                                      annotations):
+    cfg, model, params = tiny_model
+    scfg = ServeConfig(model=cfg)
+    _serve(model, scfg, _reqs(cfg, 3, max_new=3))
+    assert annotations == []
+    obs = ObsConfig(trace=True, clock=FakeClock()).resolve()
+    _serve(model, scfg, _reqs(cfg, 3, max_new=3), obs=obs)
+    entered = {name for _, name, _ in annotations}
+    assert {"engine.step", "engine.admit", "engine.decode.dispatch",
+            "engine.trace.decode"} <= entered
+    assert annotations[0][0] == "Step"
+
+
+@pytest.fixture
+def named_programs(monkeypatch):
+    """name -> (jitted step, impl, bound statics, abstract args of its
+    first call), for every program the engine builds by name."""
+    seen = {}
+    real = engine_mod._named_jit
+
+    def spy(impl, name, **static):
+        fn = real(impl, name, **static)
+
+        def call(*args):
+            seen.setdefault(name, (fn, impl, static, jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)))
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(engine_mod, "_named_jit", spy)
+    return seen
+
+
+_NAME_RE = {"decode": r"decode_b\d+_s\d+", "prefill": r"prefill_b\d+",
+            "verify": r"verify_k\d+_b\d+_s\d+",
+            "sprefill": r"sprefill_b\d+_q\d+"}
+
+
+@pytest.mark.parametrize("case", ["dense", "paged_shared_spec"])
+def test_engine_programs_lower_under_stable_names(tiny_model, case,
+                                                  named_programs):
+    """Each engine program lowers to the module ``jit_<name>``, and to
+    the same program text as the anonymous partial it replaced."""
+    cfg, model, params = tiny_model
+    if case == "dense":
+        scfg, spec, want = ServeConfig(model=cfg), None, \
+            {"decode", "prefill"}
+        prompts = _REPEATS
+    else:
+        scfg = ServeConfig(model=cfg, cache_layout="paged",
+                           cache_page_size=8, prefill_bucket=16,
+                           seqlen_bucket=16, share_prefix=True)
+        spec, want = SpecConfig(method="ngram", k=3), \
+            {"prefill", "sprefill", "verify"}
+        base = [4, 5, 6, 7] * 4
+        prompts = [base + [1, 2], base + [3, 3, 2]]
+    eng = ServingEngine(model, scfg, max_len=96, batch_slots=2)
+    eng.load(params)
+    for i, p in enumerate(prompts):
+        eng.submit(Request(i, p, max_new_tokens=16,
+                           sampling=SamplingParams(speculation=spec)))
+        eng.step()
+    eng.drain()
+    kinds = set()
+    for name, (fn, impl, static, args) in named_programs.items():
+        kind = name.split("_", 1)[0]
+        assert re.fullmatch(_NAME_RE[kind], name), name
+        kinds.add(kind)
+        text = fn.lower(*args).as_text()
+        assert text.startswith(f"module @jit_{name} ")
+        anon = jax.jit(functools.partial(impl, **static),
+                       donate_argnums=(1,)).lower(*args).as_text()
+        assert "_unknown" not in text
+        assert text == anon.replace("jit__unknown", f"jit_{name}")
+    assert want <= kinds, kinds
+
+
+def test_program_traces_total_counts_each_program_once(tiny_model):
+    cfg, model, params = tiny_model
+    obs = ObsConfig(metrics=True, clock=FakeClock()).resolve()
+    eng = ServingEngine(model, ServeConfig(model=cfg), max_len=64,
+                        batch_slots=2, obs=obs)
+    eng.load(params)
+
+    def traces():
+        return obs.metrics_snapshot()["metrics"][
+            "program_traces_total"]["series"]
+
+    for r in _reqs(cfg, 3, max_new=3):
+        eng.submit(r)
+    eng.drain()
+    warm = traces()
+    programs = len(eng.sched.plans)
+    assert sum(warm.values()) == programs >= 2
+    assert set(warm) == {"kind=decode", "kind=prefill"}
+    for r in _reqs(cfg, 3, seed=1, max_new=3):
+        eng.submit(r)
+    eng.drain()
+    assert len(eng.sched.plans) == programs
+    assert traces() == warm, "a warm program was traced again"
 
 
 @settings(max_examples=4, deadline=None)
@@ -576,3 +825,43 @@ def test_dp2_trace_merges_both_shards_bit_identical_tokens(tmp_path):
     print("OK dp2 obs parity")
     """)
     assert "OK dp2 obs parity" in out
+
+
+@pytest.mark.multidevice
+def test_sp2_engine_spans_nest_on_the_sharded_path():
+    out = run_py("""
+    import jax
+    from repro.configs.base import ServeConfig
+    from repro.configs.reduced import reduced_config
+    from repro.models import build_model
+    from repro.obs import ObsConfig, validate_trace
+    from repro.serving import Request
+    from repro.shard import ShardSpec, ShardedServingEngine
+
+    cfg = reduced_config("qwen2.5-3b", num_layers=2, d_model=32)
+    model = build_model(cfg)
+    obs = ObsConfig(trace=True, metrics=True).resolve()
+    eng = ShardedServingEngine(
+        model, ServeConfig(model=cfg),
+        spec=ShardSpec(dp=1, sp=2, slots_per_shard=2), max_len=64,
+        obs=obs)
+    eng.load(model.init_params(jax.random.PRNGKey(0)))
+    for i in range(3):
+        eng.submit(Request(i, [3 + i, 4, 5, 6, 7], max_new_tokens=4))
+    eng.drain()
+    art = obs.tracer.artifact()
+    validate_trace(art.to_json())
+    names = sorted({e["name"] for e in art.spans(cat="engine")})
+    launch = {e["args"]["mesh_splits"] for e in art.spans("decode")}
+    series = obs.metrics_snapshot()["metrics"]["program_traces_total"]
+    print("SPANS", names, launch, sorted(series["series"]))
+    """, devices=2)
+    line = [ln for ln in out.splitlines() if ln.startswith("SPANS")][0]
+    for name in ("engine.step", "engine.admit", "engine.prefill.dispatch",
+                 "engine.prefill.sync", "engine.decode.prepare",
+                 "engine.decode.dispatch", "engine.decode.sync",
+                 "engine.emit", "engine.trace.decode",
+                 "engine.trace.prefill"):
+        assert repr(name) in line, line
+    assert "{2}" in line, line
+    assert "'kind=decode,shard=0'" in line or "kind=decode" in line, line

@@ -493,6 +493,38 @@ def test_sp4_long_context_decode_with_mesh_provenance():
 
 
 @pytest.mark.multidevice
+def test_sp4_load_makes_each_cache_shard_in_place():
+    """A sequence-sharded engine makes its dense cache on the mesh: the
+    storage is only ever traced, never made whole on one device (at
+    published sizes the whole cache beside the weights overflows a
+    chip), and each device holds a quarter of every leaf's rows."""
+    run_py(_SETUP + """
+    from repro.cache.manager import CacheManager
+    made = []
+    real = CacheManager.init_storage
+    def spy(self):
+        out = real(self)
+        made.extend(isinstance(x, jax.core.Tracer)
+                    for x in jax.tree.leaves(out))
+        return out
+    CacheManager.init_storage = spy
+
+    eng = ShardedServingEngine(
+        model, ServeConfig(model=cfg),
+        spec=ShardSpec(dp=1, sp=4, slots_per_shard=2), max_len=256)
+    eng.load(params)
+    assert made and all(made), made
+    for leaf in jax.tree.leaves(eng.cores[0]._caches):
+        rows = {s.data.shape[2] for s in leaf.addressable_shards}
+        assert len(leaf.addressable_shards) == 4 and rows == {64}, rows
+    for r in reqs(2):
+        eng.submit(r)
+    assert all(len(c.tokens) == 6 for c in eng.drain())
+    print("sp4 load OK")
+    """)
+
+
+@pytest.mark.multidevice
 def test_dp2_paged_budget_exhaustion_is_per_shard():
     """One shard exhausting ITS page budget finishes only ITS request
     with cache_capacity — the other shard's identical budget is

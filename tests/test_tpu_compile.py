@@ -135,3 +135,26 @@ def test_decode_attention_pallas_compiles(compile_v5e, quantized):
 
     text = compile_v5e(fn, *shapes)
     assert text.count("tpu_custom_call") >= 2, "split + combine kernels"
+
+
+def test_prefill_attention_pallas_compiles_on_a_mesh(topo, compile_v5e):
+    """The prefill attention of a sequence-sharded engine (a 1 x 4 mesh
+    bound by ``activation_mesh``): GSPMD cannot partition a Mosaic
+    kernel, so each chip must run it on the whole prompt."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.sharding.ctx import activation_mesh
+
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+
+    def fn(q, k, v):
+        with activation_mesh(mesh):
+            return ops.attention(q, k, v, causal=True, impl="pallas",
+                                 interpret=False)
+
+    rep = NamedSharding(mesh, P())
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=rep)
+            for s in ((1, PROMPT, HQ, D), (1, PROMPT, HKV, D),
+                      (1, PROMPT, HKV, D))]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
