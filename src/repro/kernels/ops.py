@@ -508,6 +508,19 @@ def verify_attention(
     return ref.verify_decode_xla(q, k, v, pos, s, scale=scale)
 
 
+def seq_shard_plan(plan: Optional[LaunchPlan] = None
+                   ) -> Optional[LaunchPlan]:
+    """The plan that puts a decode's cache write on a sequence-sharded
+    mesh, or None: ``plan`` where it has a ``seq_shard_mesh`` (an
+    explicit plan overrides the ambient scope, the precedence of
+    :func:`decode_attention`), else the ambient decode scope where it
+    has one."""
+    for p in (plan, current_plan("decode")):
+        if p is not None and p.seq_shard_mesh is not None:
+            return p
+    return None
+
+
 def decode_attention_update(
     q: jax.Array,            # (B, Hq, Dq) — new token's queries (UNscaled)
     cache_k: jax.Array,      # (B, L, Hkv, Dk)
@@ -540,16 +553,10 @@ def decode_attention_update(
 
     Returns (out (B, Hq, Dv), new_cache_k, new_cache_v).
     """
-    scope = current_plan("decode")
     if plan is None:
         plan = metadata
-    # explicit plan overrides the ambient scope (same precedence as
-    # decode_attention); a plan without a mesh defers to the scope
-    if plan is not None and plan.seq_shard_mesh is not None:
-        shard = plan
-    else:
-        shard = scope
-    if shard is not None and shard.seq_shard_mesh is not None:
+    shard = seq_shard_plan(plan)
+    if shard is not None:
         return _decode_seqsharded(
             q, cache_k, cache_v, k_new, v_new, t, kv_len,
             mesh=shard.seq_shard_mesh, axis=shard.seq_shard_axis,

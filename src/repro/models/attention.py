@@ -340,6 +340,45 @@ def dequantize_kv(q: jax.Array, scale: jax.Array) -> jax.Array:
     return _INT8.dequantize(q, scale)
 
 
+def _decode_rows(params: Params, cfg: ModelConfig, x: jax.Array,
+                 t: jax.Array, qz: Optional[Quantizer] = None
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
+    """Project one new token per slot.  x: (B, 1, d), ``t`` scalar or
+    (B,).  Returns (q (B, Hq, D), the new cache row of every leaf
+    ``{"k", "v"}`` (B, Hkv, D) — plus ``{"k_s", "v_s"}`` (B, Hkv), the
+    rows quantized by ``qz`` before the write, where the cache is
+    quantized — and the positions (B,) int32)."""
+    B = x.shape[0]
+    tv = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (B,))
+    q, k, v = _project_qkv(params, cfg, x, tv[:, None])
+    if qz is None:
+        return q[:, 0], {"k": k[:, 0], "v": v[:, 0]}, tv
+    kq, ks = qz.quantize(k[:, 0])
+    vq, vs = qz.quantize(v[:, 0])
+    return q[:, 0], {"k": kq, "v": vq, "k_s": ks, "v_s": vs}, tv
+
+
+def _write_rows(cache: Dict[str, jax.Array], rows: Dict[str, jax.Array],
+                t: jax.Array, layer: Optional[jax.Array] = None
+                ) -> Dict[str, jax.Array]:
+    """Write slot ``b``'s new row into every leaf at position ``t[b]``.
+
+    Leaves are (B, L, ...), or a group's layer-stacked (layers, B, L,
+    ...) with ``layer`` the row's layer: one scatter of B rows a leaf,
+    which XLA performs in place on a buffer nothing else reads.  A
+    position past the end lands on the last row, as
+    ``dynamic_update_slice`` clamps it.
+    """
+    B = t.shape[0]
+    L = cache["k"].shape[1 if layer is None else 2]
+    idx = (jnp.arange(B), jnp.clip(t, 0, L - 1))
+    if layer is not None:
+        idx = (layer,) + idx
+    return {name: leaf.at[idx].set(rows[name].astype(leaf.dtype),
+                                   unique_indices=True)
+            for name, leaf in cache.items()}
+
+
 def attention_decode(
     params: Params,
     cfg: ModelConfig,
@@ -350,16 +389,20 @@ def attention_decode(
     plan: Optional[LaunchPlan] = None,
     window: Optional[int] = None,
     impl: Optional[str] = None,
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decode step. Returns (output (B,1,d), updated cache).
 
     ``t``: scalar or (B,) — position of each sequence's new token.
+    ``layer``: ``cache`` is a group's whole layer-stacked cache, the new
+    rows are written into it in place at ``[layer, b, t_b]``, and the
+    layer is then sliced out and attended; the stack is returned.
+    Without it ``cache`` is one layer's.  A sequence-sharded plan takes
+    the fused ``ops.decode_attention_update`` path, which writes each
+    chip's shard inside its ``shard_map`` (one layer's cache only).
     """
-    B = x.shape[0]
-    tv = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (B,))
-    positions = tv[:, None]
-    q, k_new, v_new = _project_qkv(params, cfg, x, positions)
-    cache_len = cache["k"].shape[1]
+    q, rows, tv = _decode_rows(params, cfg, x, t, Quantizer.for_cache(cache))
+    cache_len = cache["k"].shape[1 if layer is None else 2]
     # windowed layers attend over the ring cache — a different L_K than
     # the full-cache shape any frozen plan (explicit or ambient scope)
     # describes, so the frozen decision is dropped here (policy overrides
@@ -376,26 +419,29 @@ def attention_decode(
     else:
         write_t = tv
         kv_len = tv + 1
-    if "k_s" in cache:                      # quantized KV cache (§Perf C.4)
-        # rows are quantized before the write (a raw write would cast bf16
-        # straight into the storage dtype).  impl="pallas" here means the
-        # fused in-register-dequant kernel, not the bf16 one.
-        qz = Quantizer.for_cache(cache)
-        kq, kns = qz.quantize(k_new[:, 0])
-        vq, vns = qz.quantize(v_new[:, 0])
-        out, ck, cv, ks, vs = ops.decode_attention_update(
-            q[:, 0], cache["k"], cache["v"], kq, vq, write_t, kv_len,
-            plan=plan, use_ctx_metadata=use_ctx_md,
-            impl=impl or cfg.attention_impl,
-            quant={"k_s": cache["k_s"], "v_s": cache["v_s"],
-                   "k_ns": kns, "v_ns": vns})
-        cache = {"k": ck, "v": cv, "k_s": ks, "v_s": vs}
+    impl = impl or cfg.attention_impl
+    if ops.seq_shard_plan(plan) is not None:
+        assert layer is None, "a sequence-sharded cache is written per layer"
+        quant = None
+        if "k_s" in cache:
+            quant = {"k_s": cache["k_s"], "v_s": cache["v_s"],
+                     "k_ns": rows["k_s"], "v_ns": rows["v_s"]}
+        out, *leaves = ops.decode_attention_update(
+            q, cache["k"], cache["v"], rows["k"], rows["v"], write_t,
+            kv_len, plan=plan, use_ctx_metadata=use_ctx_md, impl=impl,
+            quant=quant)
+        cache = dict(zip(("k", "v", "k_s", "v_s"), leaves))
     else:
-        out, ck, cv = ops.decode_attention_update(
-            q[:, 0], cache["k"], cache["v"], k_new[:, 0], v_new[:, 0],
-            write_t, kv_len, plan=plan, use_ctx_metadata=use_ctx_md,
-            impl=impl or cfg.attention_impl)
-        cache = {"k": ck, "v": cv}
+        cache = _write_rows(cache, rows, write_t, layer)
+        view = cache if layer is None else {
+            name: jax.lax.dynamic_index_in_dim(leaf, layer, keepdims=False)
+            for name, leaf in cache.items()}
+        # a quantized cache's scales ride in: pallas runs the fused
+        # in-register-dequant kernel, xla dequantizes up front
+        out = ops.decode_attention(
+            q, view["k"], view["v"], kv_len, k_scale=view.get("k_s"),
+            v_scale=view.get("v_s"), plan=plan,
+            use_ctx_metadata=use_ctx_md, impl=impl)
     y = jnp.einsum("bhk,hkd->bd", out, params["wo"])
     return y[:, None], cache
 

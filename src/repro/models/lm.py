@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.models import attention as attn_mod
 from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
@@ -214,8 +215,11 @@ def block_cache_specs(cfg: ModelConfig, kind: str, batch: int,
 
 def block_decode(params, cfg: ModelConfig, kind: str, x: jax.Array,
                  cache: Pytree, t: jax.Array, *,
-                 plan=None) -> Tuple[jax.Array, Pytree]:
+                 plan=None, layer=None) -> Tuple[jax.Array, Pytree]:
     """One block, one token. x: (B, 1, d).
+
+    ``layer`` (``attn`` blocks): ``cache`` is the group's whole stacked
+    cache, written in place at that layer (``attention_decode``).
 
     ``plan`` is the frozen :class:`~repro.plan.LaunchPlan` (static); it
     applies to full-attention layers, which all see the same decode
@@ -228,7 +232,7 @@ def block_decode(params, cfg: ModelConfig, kind: str, x: jax.Array,
     h = apply_norm(params["ln1"], x, cfg.norm_eps)
     if kind == "attn":
         mix, cache = attn_mod.attention_decode(
-            params["mix"], cfg, h, cache, t, plan=plan)
+            params["mix"], cfg, h, cache, t, plan=plan, layer=layer)
     elif kind == "attn_window":
         mix, cache = attn_mod.attention_decode(
             params["mix"], cfg, h, cache, t, plan=plan,
@@ -584,6 +588,56 @@ def lm_prefill_suffix_view(
 # ---------------------------------------------------------------------------
 
 
+def _writes_in_place(pattern: Tuple[str, ...], plan=None) -> bool:
+    """Whether a decode step writes a group's cache in place: a group of
+    full-attention layers, whose per-layer cache is the dense ``k``/``v``
+    dict (with its scales where quantized), and no sequence-sharded plan
+    (explicit or ambient), whose write stays in its ``shard_map``."""
+    return pattern == ("attn",) and ops.seq_shard_plan(plan) is None
+
+
+def decode_inplace_layers(cfg: ModelConfig, plan=None) -> int:
+    """How many layers :func:`lm_decode_step` writes in place under
+    ``plan``; the others go through the scan's outputs.  Encoder-decoder
+    models decode through ``encdec.py``'s own scan."""
+    if cfg.family == "encdec":
+        return 0
+    return sum(reps for pattern, reps in layer_groups(cfg)
+               if _writes_in_place(pattern, plan))
+
+
+def decode_cache_update(cfg: ModelConfig, plan=None) -> str:
+    """The decode step's cache-update path under ``plan``: ``"in_place"``
+    (the layer scan carries the stacked cache and writes one row per
+    slot and layer) or ``"scan"`` (each layer's cache goes in as the
+    scan's input and comes back rewritten as its output)."""
+    return "in_place" if decode_inplace_layers(cfg, plan) else "scan"
+
+
+def _decode_group_in_place(gp, cfg: ModelConfig, gc, x: jax.Array,
+                           t: jax.Array, reps: int, plan=None):
+    """One ``("attn",)`` group's decode with its stacked cache in the
+    scan's carry, next to the layer index: each layer writes its new rows
+    into the stack in place and attends the layer sliced out of it, so
+    the cache is neither copied whole nor rebuilt through the scan's
+    outputs."""
+    def body(carry, layer_params):
+        xc, stack, layer = carry
+        xc = shard_activation(xc, _ACT)
+        xc, stack = block_decode(layer_params[0], cfg, "attn", xc, stack,
+                                 t, plan=plan, layer=layer)
+        return (shard_activation(xc, _ACT), stack, layer + 1), None
+
+    stack = gc[0]
+    if cfg.scan_layers:
+        (x, stack, _), _ = jax.lax.scan(body, (x, stack, jnp.int32(0)), gp)
+    else:
+        for r in range(reps):
+            (x, stack, _), _ = body((x, stack, r),
+                                    jax.tree.map(lambda a: a[r], gp))
+    return x, (stack,)
+
+
 def lm_decode_step(
     params: Pytree,
     cfg: ModelConfig,
@@ -597,7 +651,9 @@ def lm_decode_step(
 
     ``plan``: precomputed :class:`~repro.plan.LaunchPlan` (the
     metadata-enabled path); threaded into every attention block so the
-    split policy never runs inside this (traced) function.
+    split policy never runs inside this (traced) function.  Groups of
+    full-attention layers write the cache in place
+    (:func:`decode_cache_update`).
     """
     x = embed_tokens(params["embed"], token[:, None])    # (B, 1, d)
     x = shard_activation(x, _ACT)
@@ -606,6 +662,11 @@ def lm_decode_step(
     for gi, (pattern, reps) in enumerate(layer_groups(cfg)):
         gp = params["groups"][gi]
         gc = caches[gi]
+        if _writes_in_place(pattern, plan):
+            x, nc = _decode_group_in_place(gp, cfg, gc, x, t, reps,
+                                           plan=plan)
+            new_caches.append(nc)
+            continue
 
         def body(xc, scanned, pattern=pattern):
             layer_params, layer_cache = scanned

@@ -232,6 +232,10 @@ class Observer:
             "program_traces_total",
             "times JAX traced an engine program, by kind (a retrace "
             "counts even where the compile cache hides the compile)")
+        self._m_decode_traces = m.counter(
+            "decode_program_traces_total",
+            "times JAX traced a decode program, by the path its step "
+            "updates the KV cache on (in_place | scan)")
         self._m_slots_live = m.gauge(
             "slots_live", "occupied decode slots (last step)").labels(**lb)
         self._m_slots_total = m.gauge(
@@ -322,13 +326,23 @@ class Observer:
             ann = TraceAnnotation(name, **args)
         return _Span(self, name, ann, args)
 
-    def program_trace(self, kind: str) -> _Span:
+    def program_trace(self, kind: str, **args: int) -> _Span:
         """Count one trace of a ``kind`` engine program and return its
-        ``engine.trace.<kind>`` span.  Entered from the program's Python
-        body, which runs only while JAX traces it."""
+        ``engine.trace.<kind>`` span, with ``args``.  Entered from the
+        program's Python body, which runs only while JAX traces it."""
         if self.metrics is not None:
             self._m_traces.inc(1, kind=kind, **self.labels)
-        return self.span(f"engine.trace.{kind}")
+        return self.span(f"engine.trace.{kind}", **args)
+
+    def decode_trace(self, cache_update: str, inplace_layers: int) -> _Span:
+        """:meth:`program_trace` of a decode program that also records
+        its step's cache-update path: ``cache_update`` (``in_place`` or
+        ``scan``) counts in ``decode_program_traces_total`` and
+        ``inplace_layers`` rides on the span."""
+        if self.metrics is not None:
+            self._m_decode_traces.inc(1, cache_update=cache_update,
+                                      **self.labels)
+        return self.program_trace("decode", inplace_layers=inplace_layers)
 
     def on_launch(self, kind: str, key: Any, plan: Any, t0: int,
                   t1: int) -> None:

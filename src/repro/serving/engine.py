@@ -112,6 +112,7 @@ import numpy as np
 
 from repro.configs.base import ServeConfig
 from repro.core.split_policy import KV_DTYPES, get_policy
+from repro.models import lm
 from repro.models.registry import Model
 from repro.obs import atomic_write_json, resolve_obs
 from repro.obs.observer import NO_SPAN
@@ -471,6 +472,15 @@ class ServingEngine:
         return self._obs.program_trace(kind) if self._obs.enabled \
             else NO_SPAN
 
+    def _traced_decode(self, plan: Optional[LaunchPlan]) -> Any:
+        """:meth:`_traced` for a decode program under ``plan``, which also
+        records the path its step updates the cache on."""
+        if not self._obs.enabled:
+            return NO_SPAN
+        cfg = self.model.cfg
+        return self._obs.decode_trace(lm.decode_cache_update(cfg, plan),
+                                      lm.decode_inplace_layers(cfg, plan))
+
     # --- state --------------------------------------------------------------
 
     def load(self, params: Pytree) -> None:
@@ -518,7 +528,7 @@ class ServingEngine:
 
     def _decode_impl(self, params, caches, token, t, state,
                      plan: Optional[LaunchPlan] = None):
-        with self._traced("decode"):
+        with self._traced_decode(plan):
             with activation_mesh(self.mesh):
                 logits, caches = self.model.decode_step(
                     params, caches, token, t, plan=plan,
@@ -567,7 +577,7 @@ class ServingEngine:
         residency, not the padded slot capacity.
         """
         lay = self.cache.layout
-        with self._traced("decode"):
+        with self._traced_decode(plan):
             view = lay.gather_view(storage, table, num_pages)
             with activation_mesh(self.mesh):
                 logits, view = self.model.decode_step(

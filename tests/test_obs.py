@@ -634,6 +634,25 @@ def test_program_traces_total_counts_each_program_once(tiny_model):
     assert traces() == warm, "a warm program was traced again"
 
 
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_decode_traces_name_the_cache_update_path(tiny_model, layout):
+    """Each decode program's trace says which path its step updates the
+    KV cache on: a one-device engine writes every layer in place."""
+    cfg, model, params = tiny_model
+    obs = ObsConfig(trace=True, metrics=True, clock=FakeClock()).resolve()
+    _serve(model, ServeConfig(model=cfg, cache_layout=layout),
+           _reqs(cfg, 3, max_new=3), obs=obs)
+    metrics = obs.metrics_snapshot()["metrics"]
+    decodes = metrics["program_traces_total"]["series"]["kind=decode"]
+    assert metrics["decode_program_traces_total"]["series"] == \
+        {"cache_update=in_place": decodes}
+    spans = [e for e in obs.tracer.artifact().spans(cat="engine")
+             if e["name"] == "engine.trace.decode"]
+    assert len(spans) == decodes >= 1
+    assert {e["args"]["inplace_layers"] for e in spans} == \
+        {cfg.num_layers}
+
+
 @settings(max_examples=4, deadline=None)
 @given(layout=st.sampled_from(["dense", "paged"]),
        sharded=st.sampled_from([False, True]),
@@ -854,7 +873,13 @@ def test_sp2_engine_spans_nest_on_the_sharded_path():
     names = sorted({e["name"] for e in art.spans(cat="engine")})
     launch = {e["args"]["mesh_splits"] for e in art.spans("decode")}
     series = obs.metrics_snapshot()["metrics"]["program_traces_total"]
+    paths = obs.metrics_snapshot()["metrics"][
+        "decode_program_traces_total"]["series"]
+    layers = {e["args"]["inplace_layers"]
+              for e in art.spans(cat="engine")
+              if e["name"] == "engine.trace.decode"}
     print("SPANS", names, launch, sorted(series["series"]))
+    print("PATHS", sorted(paths), layers)
     """, devices=2)
     line = [ln for ln in out.splitlines() if ln.startswith("SPANS")][0]
     for name in ("engine.step", "engine.admit", "engine.prefill.dispatch",
@@ -865,3 +890,6 @@ def test_sp2_engine_spans_nest_on_the_sharded_path():
         assert repr(name) in line, line
     assert "{2}" in line, line
     assert "'kind=decode,shard=0'" in line or "kind=decode" in line, line
+    # the sequence-sharded decode writes its cache inside its shard_map
+    paths = [ln for ln in out.splitlines() if ln.startswith("PATHS")][0]
+    assert paths == "PATHS ['cache_update=scan,shard=0'] {0}", paths

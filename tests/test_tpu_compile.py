@@ -158,3 +158,58 @@ def test_prefill_attention_pallas_compiles_on_a_mesh(topo, compile_v5e):
                       (1, PROMPT, HKV, D))]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_decode_step_writes_the_cache_in_place(topo, compile_v5e,
+                                               monkeypatch):
+    """The whole decode step at qwen2.5-3b widths (8 layers), the cache
+    donated as the serving engine donates it: the layer scan writes the
+    new rows into the stacked cache in place, so no whole-cache copy is
+    left in the program and its temporaries stay under two layers' K+V
+    (the scan that rebuilt the cache through its outputs needed more
+    than the whole stack)."""
+    import dataclasses
+    import re
+    from repro.configs.base import ModelConfig
+    from repro.kernels import flash_combine, flash_decode
+    from repro.models import build_model
+
+    # the step asks for interpret=None, which the CPU backend resolves to
+    # the interpreter: compile the Mosaic kernels for the described chip
+    for mod in (flash_decode, flash_combine):
+        monkeypatch.setattr(mod, "resolve_interpret", lambda i=None: False)
+    layers = 8
+    cfg = ModelConfig(name="qwen2.5-3b-8l", family="dense",
+                      num_layers=layers, d_model=2048, num_heads=HQ,
+                      num_kv_heads=HKV, d_ff=11008, vocab_size=151936,
+                      head_dim=D, qkv_bias=True, rope_theta=1e6,
+                      tie_embeddings=True, attention_impl="pallas")
+    model = build_model(cfg)
+    plan = dataclasses.replace(
+        get_scheduler_metadata(B, 1, L, HQ, HKV, D), impl="pallas")
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+            tree)
+
+    def step(params, caches, token, t):
+        return model.decode_step(params, caches, token, t, plan=plan)
+
+    vec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=chip)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        shaped(model.abstract_params()),
+        shaped(model.abstract_cache(B, L)), vec, vec).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+
+    layer_kv = 2 * B * L * HKV * D * 2           # K + V of one layer, bf16
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * layer_kv, (temp, layer_kv)
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    stack = f"bf16[{layers},{B},{L},{HKV},{D}]"
+    copies = [ln for ln in entry.splitlines()
+              if re.search(r"\scopy(-start)?\(", ln) and stack in ln]
+    assert not copies, copies
