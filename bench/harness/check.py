@@ -2,9 +2,11 @@
 
 After the window, a sample of the served requests, drawn from the seed
 and always holding the one with the most served tokens, is replayed
-through the plain float32 reference, teacher-forced on each prompt and
-its served tokens.  Each served (greedy) token gets its gap: how far its
-logit lies below the reference's best logit of its row.  Two numbers are
+through the plain float32 reference that the cell's configuration names
+(``cell.reference``, on weights mapped back by ``cell.adapter``),
+teacher-forced on each prompt and its served tokens.  Each served
+(greedy) token gets its gap: how far its logit lies below the
+reference's best logit of its row.  Two numbers are
 read from the gaps of the whole sample: the widest (``max_logit_gap``)
 and the share of tokens that are not the reference's first
 (``flip_share``, in %).  The cell's limits are in
@@ -23,9 +25,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from bench.harness import model as bmodel
 from bench.harness.drive import ReqRecord
-from bench.reference import qwen2
 
 NUMBERS = ("max_logit_gap", "flip_share")
 
@@ -47,17 +47,18 @@ def sample(records: Dict[int, ReqRecord], k: int, seed: int
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def gaps(params, cfg: Dict, prompts: Dict[int, List[int]],
+def gaps(cell, params, prompts: Dict[int, List[int]],
          reqs: List[ReqRecord], controls: Sequence[str] = ()
          ) -> Dict[str, List[np.ndarray]]:
     """Per request, the gap of every served token (``"served"``) and of
     every token each control in ``controls`` puts first (keyed by the
-    control's type)."""
-    L = cfg["num_hidden_layers"]
-    glob = bmodel.canonical_globals(params, cfg)
+    control's type), under ``cell``'s reference."""
+    cfg, adapter, ref = cell.config, cell.adapter, cell.reference
+    L = adapter.dims(cfg)["layers"]
+    glob = adapter.canonical_globals(params, cfg)
 
     def layers():
-        return (bmodel.canonical_layer(params, cfg, i) for i in range(L))
+        return (adapter.canonical_layer(params, cfg, i) for i in range(L))
 
     out: Dict[str, List[np.ndarray]] = {"served": []}
     for c in controls:
@@ -65,13 +66,13 @@ def gaps(params, cfg: Dict, prompts: Dict[int, List[int]],
     for r in reqs:
         n = len(prompts[r.index])
         seq = list(prompts[r.index]) + list(r.tokens[:-1])
-        h = qwen2.hidden(layers(), glob, cfg, seq)
-        out["served"].append(qwen2.gaps_at(h, glob, cfg, n, r.tokens))
+        h = ref.hidden(layers(), glob, cfg, seq)
+        out["served"].append(ref.gaps_at(h, glob, cfg, n, r.tokens))
         for c in controls:
-            hc = qwen2.hidden(layers(), glob, cfg, seq, lower=c)
-            picks = qwen2.firsts_at(hc, glob, cfg, n, len(r.tokens))
+            hc = ref.hidden(layers(), glob, cfg, seq, lower=c)
+            picks = ref.firsts_at(hc, glob, cfg, n, len(r.tokens))
             del hc
-            out[c].append(qwen2.gaps_at(h, glob, cfg, n, picks))
+            out[c].append(ref.gaps_at(h, glob, cfg, n, picks))
         del h
     return out
 

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 
@@ -41,7 +41,8 @@ class DriveResult:
     records: Dict[int, ReqRecord]
     lateness: List[float]               # submit - due, open loop only
     steps: int = 0
-    stalls: List[float] = field(default_factory=list)  # see Client
+    # (seconds, clock at its start) of each step and wait: see Client
+    stalls: List[Tuple[float, float]] = field(default_factory=list)
 
 
 def _request(p: Planned):
@@ -58,9 +59,9 @@ class Client:
         self.records: Dict[int, ReqRecord] = {}
         self.next = 0                   # next planned request to submit
         # wall seconds of each step() and each wait's overshoot past its
-        # wake-up: the longest say whether a slow run stalled in the
-        # engine or on the host
-        self.stalls: List[float] = []
+        # wake-up, with the clock at its start: the longest say whether a
+        # slow run stalled in the engine or on the host, and when
+        self.stalls: List[Tuple[float, float]] = []
 
     def submit(self, due: float = 0.0) -> ReqRecord:
         p = self.planned[self.next]
@@ -78,7 +79,7 @@ class Client:
         with jax.profiler.TraceAnnotation("bench.step"):
             events = self.engine.step()
         t = clock()
-        self.stalls.append(t - t0)
+        self.stalls.append((t - t0, t0))
         done = []
         for ev in events:
             rec = self.records[ev.request_id]
@@ -212,7 +213,7 @@ def run_open(client: Client, seconds: float, wait_after: float = 60.0,
             wake = min(nxt, t_end) if now < t_end else nxt
             with jax.profiler.TraceAnnotation("bench.wait"):
                 time.sleep(max(0.0, wake - clock()))
-            client.stalls.append(max(0.0, clock() - wake))
+            client.stalls.append((max(0.0, clock() - wake), wake))
         else:
             break
     return DriveResult(t0, t_end, client.records, lateness, steps,

@@ -1,6 +1,11 @@
 """Shared arithmetic of the per-layer metrics: the operations and bytes
 the model and its kernels need, from shapes and live cache lengths.
 
+What one architecture counts (a layer's matmul parameters, a decoded
+token's FLOPs, the decode-attention kernel's work per row) comes from its
+adapter, ``bench/adapters/<model_type>.py``, which the dims a run hands
+its readers carry under ``"adapter"`` (``Cell.dims``).
+
 A decode launch processes one token per live slot; a served token with
 index ``j >= 1`` of a request with an ``n``-token prompt came from a
 decode launch whose input row sat at position ``n + j - 1``, so its
@@ -8,7 +13,7 @@ attention read ``kv_len = n + j`` cached rows.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
@@ -16,17 +21,21 @@ KV_BYTES = 2          # bf16 cache
 Q_BYTES = 4           # the kernels take the scaled query and return f32
 
 
-def layer_matmul_params(n: Dict[str, int]) -> int:
-    d, q, kv, ff = n["d"], n["hq"] * n["hd"], n["hkv"] * n["hd"], n["ff"]
-    return d * q + 2 * d * kv + q * d + 3 * d * ff
+def adapter(n: Dict[str, Any]):
+    """The adapter whose ``dims`` made ``n``, as ``Cell.dims`` carries
+    it."""
+    if "adapter" not in n:
+        raise KeyError("dims carry no 'adapter': take them from Cell.dims")
+    return n["adapter"]
 
 
-def decode_token_flops(n: Dict[str, int], kv_len: int) -> float:
-    """Model FLOPs of one decoded token: every layer's projections and
-    MLP, its attention over ``kv_len`` rows, and the output head."""
-    attn = 4 * n["hq"] * n["hd"] * kv_len
-    return (n["layers"] * (2 * layer_matmul_params(n) + attn)
-            + 2 * n["d"] * n["vocab"])
+def layer_matmul_params(n: Dict[str, Any]) -> int:
+    return adapter(n).layer_matmul_params(n)
+
+
+def decode_token_flops(n: Dict[str, Any], kv_len: int) -> float:
+    """Model FLOPs of one decoded token at ``kv_len`` cached rows."""
+    return adapter(n).decode_token_flops(n, kv_len)
 
 
 def decode_kv_lens(run) -> List[int]:
@@ -50,17 +59,18 @@ def untraced_span(run) -> Optional[Tuple[float, float]]:
     return run.window[0], run.trace_host_window[0]
 
 
-def flash_decode_need(n: Dict[str, int], kv_lens: Iterable[int],
+def flash_decode_need(n: Dict[str, Any], kv_lens: Iterable[int],
                       peak: Dict) -> float:
     """Least seconds the decode-attention kernels (split pass and
     combine) could take for these live rows in every layer: q in, o out,
     and the K/V of resident rows, against the HBM bandwidth; or their
     FLOPs against the peak, whichever is larger."""
     kv = np.asarray(list(kv_lens), np.float64)
+    qo_row, kv_row, flops_row = adapter(n).flash_decode_row(n)
     rows = len(kv) * n["layers"]
-    qo = rows * 2 * n["hq"] * n["hd"] * Q_BYTES
-    kvb = n["layers"] * kv.sum() * 2 * n["hkv"] * n["hd"] * KV_BYTES
-    flops = n["layers"] * kv.sum() * 4 * n["hq"] * n["hd"]
+    qo = rows * qo_row * Q_BYTES
+    kvb = n["layers"] * kv.sum() * kv_row * KV_BYTES
+    flops = n["layers"] * kv.sum() * flops_row
     return max((qo + kvb) / peak["hbm_bytes_s"], flops / peak["bf16_flops"])
 
 

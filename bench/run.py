@@ -11,7 +11,8 @@ fill) is timed as ``setup_s``; then the window runs for ``--seconds``
 with no compile in it.  ``--trace 1`` profiles part of the window and
 reports the cell's per-layer metrics instead of its end-to-end ones.
 After the window, a sample of what was served is compared with the
-plain float32 reference (``bench/reference``).
+plain float32 reference that the configuration names (``bench/reference``,
+on the weights mapped back by the architecture's ``bench/adapters``).
 
 The last line on standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown``
@@ -28,6 +29,8 @@ T_START = time.monotonic()
 import argparse  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
+import os  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -71,8 +74,10 @@ class CompileLog:
 
 @dataclass
 class RunData:
-    """What a per-layer metric reader gets to read."""
-    dims: Dict[str, int]
+    """What a per-layer metric reader gets to read: ``dims`` are the
+    cell's ``Cell.dims``, its architecture's sizes with its adapter
+    (``bench/adapters``) under ``"adapter"``."""
+    dims: Dict[str, Any]
     chips: int
     peak: Dict[str, Any]
     records: Dict[int, Any]
@@ -82,6 +87,53 @@ class RunData:
     queue_waits_ms: List[float]
     loop: str                           # "open" or "closed"
     window: Tuple[float, float]         # host clock: the window opens, closes
+
+
+def machine_waits() -> Tuple[float, float]:
+    """CPU seconds the machine's CPUs have spent so far waiting on I/O
+    and stolen by the hypervisor, summed over the CPUs (``/proc/stat``;
+    zeros where it cannot be read)."""
+    try:
+        with open("/proc/stat") as f:
+            v = f.readline().split()
+        hz = os.sysconf("SC_CLK_TCK")
+        return int(v[5]) / hz, int(v[8]) / hz
+    except (OSError, IndexError, ValueError):
+        return 0.0, 0.0
+
+
+class HostWatch:
+    """What the process and its machine did besides the program over the
+    window: the garbage collector's passes; from ``getrusage`` the
+    process's major page faults, involuntary context switches and CPU
+    seconds; and the machine's I/O wait and stolen CPU seconds.  A step
+    that stalls for seconds is read against these."""
+
+    def __init__(self):
+        self.passes: List[float] = []
+        self._t = 0.0
+        self._ru = resource.getrusage(resource.RUSAGE_SELF)
+        self._waits = machine_waits()
+        gc.callbacks.append(self)
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            self._t = time.perf_counter()
+        else:
+            self.passes.append(time.perf_counter() - self._t)
+
+    def stop(self) -> str:
+        gc.callbacks.remove(self)
+        a, b = self._ru, resource.getrusage(resource.RUSAGE_SELF)
+        io, steal = (y - x for x, y in zip(self._waits, machine_waits()))
+        return (f"[host] collector passes {len(self.passes)}, longest "
+                f"{1e3 * max(self.passes, default=0.0):.1f} ms, total "
+                f"{1e3 * sum(self.passes):.1f} ms; major page faults "
+                f"{b.ru_majflt - a.ru_majflt}, involuntary context "
+                f"switches {b.ru_nivcsw - a.ru_nivcsw}, CPU s user "
+                f"{b.ru_utime - a.ru_utime:.2f} system "
+                f"{b.ru_stime - a.ru_stime:.2f}; machine CPU s in I/O "
+                f"wait {io:.2f}, stolen {steal:.2f}")
 
 
 def configure_jax(root: Path) -> None:
@@ -216,7 +268,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     from bench import peaks
     from bench.harness import check, drive, model as bmodel, traffic
     cfg, tr = cell.config, cell.traffic
-    dims = bmodel.dims(cfg)
+    dims = cell.dims
     peak = peaks.peak(devices[0].device_kind) if trace else {}
     obs = ObsConfig(trace=True).resolve() if trace else None
     obs_epoch = time.monotonic() - obs.now_us() / 1e6 if obs else 0.0
@@ -251,16 +303,20 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
         traced = float(tr.get("trace_seconds", 3.0))
         tracer = Tracer(max(0.0, seconds - traced), traced, tdir)
     run = drive.run_closed if closed else drive.run_open
+    watch = HostWatch()
     result = run(client, seconds, on_tick=tracer)
     t_drive_end = time.monotonic()
+    host = watch.stop()
     if tracer is not None:
         tracer.stop()
     in_window = compiles.between(t_setup_end, t_drive_end)
+    longest = [(round(1e3 * d, 1), round(t - result.t0, 2))
+               for d, t in sorted(result.stalls)[-3:]]
     log(f"[window] {seconds} s, {result.steps} engine steps; compiles in "
         f"the window: {len(in_window)} (plan-cache misses "
         f"{engine.stats.misses - misses0}); longest steps or waits "
-        f"past their wake-up, ms: "
-        f"{[round(1e3 * x, 1) for x in sorted(result.stalls)[-3:]]}")
+        f"past their wake-up, ms (at s into the window): {longest}")
+    log(host)
     if result.lateness:
         late = np.asarray(result.lateness) * 1e3
         log(f"[traffic] generator lateness ms: median "
@@ -332,7 +388,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     gc.collect()
     t_ref = time.monotonic()
     sample = check.sample(recs, int(cell.check.get("sample", 4)), seed)
-    gaps = check.gaps(params, cfg, prompts, sample, controls)
+    gaps = check.gaps(cell, params, prompts, sample, controls)
     log(f"[check] reference over {len(sample)} requests "
         f"({[len(r.tokens) for r in sample]} served tokens) took "
         f"{time.monotonic() - t_ref:.1f} s")

@@ -45,10 +45,10 @@ def main(argv=None) -> int:
     devices = require_chips(cell.chips)
     engine, _ = bmodel.build(cell, args.seed, devices)
     tr = dict(cell.traffic)
+    vocab = cell.dims["vocab"]
     for rate in (float(r) for r in args.rates.split(",")):
         tr["arrivals"] = dict(tr["arrivals"], rate=rate)
-        planned = traffic.generate(tr, bmodel.dims(cell.config)["vocab"],
-                                   args.seed, args.seconds)
+        planned = traffic.generate(tr, vocab, args.seed, args.seconds)
         drive.warm_up(engine, planned)
         backlog = []
         d = drive.Client(engine, planned)

@@ -14,10 +14,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from bench import run as brun
-from bench.reference import qwen2
-from bench.tests.tiny import make_root
+from bench.harness.cell import load_cell
+from bench.tests.tiny import REPO, make_root
+
+
+@pytest.fixture(scope="module")
+def reference():
+    """The reference the harness loads for qwen2.5-3b's cells."""
+    return load_cell(REPO, "qwen2.5-3b.longctx-decode").reference
 
 
 def _matrix():
@@ -25,9 +32,9 @@ def _matrix():
         jnp.linspace(0.1, 3.0, 32)[None, :]
 
 
-def test_int8_rounding_has_one_scale_per_output_column():
+def test_int8_rounding_has_one_scale_per_output_column(reference):
     w = _matrix()
-    q = qwen2.round_weights(w, "int8")
+    q = reference.round_weights(w, "int8")
     scale = np.abs(np.asarray(w)).max(axis=0) / 127.0
     steps = np.asarray(q) / scale[None, :]
     np.testing.assert_allclose(steps, np.round(steps), atol=1e-3)
@@ -36,9 +43,10 @@ def test_int8_rounding_has_one_scale_per_output_column():
     assert not np.array_equal(np.asarray(q), np.asarray(w))
 
 
-def test_fp8_rounding_keeps_three_mantissa_bits_per_scaled_column():
+def test_fp8_rounding_keeps_three_mantissa_bits_per_scaled_column(
+        reference):
     w = _matrix()
-    q = np.asarray(qwen2.round_weights(w, "fp8"))
+    q = np.asarray(reference.round_weights(w, "fp8"))
     scale = np.abs(np.asarray(w)).max(axis=0) / 448.0
     x = q / scale[None, :]
     assert np.abs(x).max() <= 448 * (1 + 1e-5)

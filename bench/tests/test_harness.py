@@ -29,12 +29,14 @@ def test_cell_added_as_files_only_is_found(tmp_path):
                                "layer": "device", "moves": "setup_s",
                                "workloads": ["dummy.mix"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    (root / "bench/configs/dummy.json").write_text('{"name": "dummy"}')
+    dummy = {"name": "dummy", "model_type": "qwen2",
+             "reference": "bench/reference/qwen2.py"}
+    (root / "bench/configs/dummy.json").write_text(json.dumps(dummy))
     (root / "bench/traffic/dummy-mix.json").write_text('{"loop": "open"}')
     (root / "bench/metrics/dummy_metric.py").write_text(
         "def read(run):\n    return 42.0 if run == 'x' else None\n")
     c = bcell.load_cell(root, "dummy.mix")
-    assert c.config == {"name": "dummy"}
+    assert c.config == dummy
     assert c.traffic == {"loop": "open"}
     assert "dummy_metric" in [m["name"] for m in c.per_layer]
     assert c.readers["dummy_metric"]("x") == 42.0

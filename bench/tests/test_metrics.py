@@ -11,13 +11,14 @@ import pytest
 
 from bench.harness.devtrace import Event, Trace
 from bench.harness.drive import ReqRecord
-from bench.harness.cell import load_reader
+from bench.harness.cell import load_adapter, load_reader
 from bench.run import RunData, breakdown
 from bench.tests.tiny import REPO
 
 PEAK = {"bf16_flops": 197e12, "hbm_bytes_s": 819e9}
 DIMS = {"d": 2048, "hq": 16, "hkv": 2, "hd": 128, "ff": 11008,
-        "layers": 36, "vocab": 151936}
+        "layers": 36, "vocab": 151936, "model_type": "qwen2",
+        "adapter": load_adapter(REPO, "qwen2")}
 
 
 def read(name, run):

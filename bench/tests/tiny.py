@@ -14,7 +14,8 @@ REPO = Path(__file__).resolve().parents[2]
 
 CONFIG = {
     "name": "tiny-qwen2", "source": "https://huggingface.co/Qwen/Qwen2.5-3B",
-    "model_type": "qwen2", "hidden_size": 64, "intermediate_size": 128,
+    "reference": "bench/reference/qwen2.py", "model_type": "qwen2",
+    "hidden_size": 64, "intermediate_size": 128,
     "num_attention_heads": 4, "num_key_value_heads": 2,
     "num_hidden_layers": 2, "vocab_size": 512,
     "max_position_embeddings": 256, "rms_norm_eps": 1e-06,
